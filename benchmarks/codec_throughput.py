@@ -3,8 +3,8 @@
 Three sections, one CSV (``benchmarks/results/codec_throughput.csv``):
 
 1. **encode** — batched systematic encode MB/s per backend and batch
-   size (`storage.codec.encode_batch`; the whole batch folds into one
-   GF(256) matmul).
+   size (`storage.codec.encode_batch`; the whole batch is one batched
+   GF(256) matmul against the shared parity matrix).
 2. **decode** — batched degraded-read decode MB/s per backend and batch
    size (`storage.codec.decode_batch`; decode-matrix bank gathered on
    device, one `gf256_matmul_batch` call per (n, k) group).

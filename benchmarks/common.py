@@ -1,6 +1,7 @@
 """Shared benchmark scaffolding: the paper's testbed scenario + CSV sink."""
 from __future__ import annotations
 
+import os
 import time
 from pathlib import Path
 
@@ -12,6 +13,19 @@ from repro.storage import tahoe_testbed
 
 RESULTS = Path(__file__).parent / "results"
 RESULTS.mkdir(exist_ok=True)
+
+
+def use_compile_cache() -> None:
+    """Keep compiled programs across benchmark processes: in
+    ``$JAX_COMPILATION_CACHE_DIR`` when it is set (JAX reads it itself),
+    else in ``.jax_cache/`` at the repo root. The path is fixed so that
+    the next process finds what this one compiled."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        root = Path(__file__).resolve().parent.parent
+        jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
+
+
+use_compile_cache()
 
 
 def emit(rows: list[dict], name: str) -> None:

@@ -28,6 +28,9 @@ MODULES = [
 
 def main() -> None:
     only = sys.argv[1].split(",") if len(sys.argv) > 1 else None
+    unknown = sorted(set(only or ()) - set(MODULES))
+    if unknown:
+        raise SystemExit(f"unknown benchmark(s) {unknown}; choose from {MODULES}")
     failed = []
     for name in MODULES:
         if only and name not in only:

@@ -17,10 +17,11 @@ loop's natural unit is an (S, m)-wide step: S seeds x m nodes per
 request index. As a ``lax.scan`` this is memory-bound — every step
 round-trips the (S, m) carry plus an (S, m) slice of the mask/service
 streams through HBM with no fusion across steps. The Pallas backend
-keeps the whole working set (carry, one request slice, accumulators)
-VMEM-resident for a block of seeds and walks the request axis in a
-``fori_loop`` inside ONE kernel launch, writing only the (S, N) latency
-block and the final (S, m) carries back out.
+keeps the carry VMEM-resident for a block of up to 128 seeds (one per
+lane, nodes on sublanes) and walks the request axis in ONE kernel
+launch: a grid axis over request blocks, a ``fori_loop`` within each,
+so every access is a whole-tile load at a leading index (Mosaic has no
+dynamic single-lane access) and VMEM does not grow with the horizon.
 
 Two interchangeable backends (same contract as `kernels/ops.py`):
 
@@ -51,19 +52,21 @@ import jax
 import jax.numpy as jnp
 from jax import Array
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _step(dep, busy, t, mask, srv):
-    """One FCFS update; shapes (..., m) with t (...,). The op sequence is
-    shared verbatim by both backends so they agree bit-for-bit."""
-    start = jnp.maximum(t[..., None], dep)
+def _step(dep, busy, t, mask, srv, axis=-1):
+    """One FCFS update over the node ``axis`` of (..., m)-shaped state;
+    ``t`` has size 1 on that axis, and so has the returned latency. The op
+    sequence is shared verbatim by both backends so they agree bit-for-bit."""
+    start = jnp.maximum(t, dep)
     finish = start + srv
     new_dep = jnp.where(mask, finish, dep)
-    latency = jnp.max(jnp.where(mask, finish, -jnp.inf), axis=-1) - t
+    latency = jnp.max(jnp.where(mask, finish, -jnp.inf), axis=axis, keepdims=True) - t
     new_busy = busy + jnp.where(mask, srv, 0.0)
     return new_dep, new_busy, latency
 
@@ -76,8 +79,8 @@ def _fcfs_scan_ref_one(
     def step(carry, inp):
         dep, busy = carry
         tt, mask, srv = inp
-        new_dep, new_busy, latency = _step(dep, busy, tt, mask, srv)
-        return (new_dep, new_busy), latency
+        new_dep, new_busy, latency = _step(dep, busy, tt[None], mask, srv)
+        return (new_dep, new_busy), latency[0]
 
     (dep, busy), latency = jax.lax.scan(
         step, (dep0, busy0), (t, masks, service)
@@ -86,25 +89,49 @@ def _fcfs_scan_ref_one(
 
 
 def _fcfs_kernel(t_ref, m_ref, s_ref, d0_ref, b0_ref, lat_ref, dep_ref, busy_ref):
-    """Fused fleet-step block: grid walks seed blocks, the fori_loop walks
-    requests; carry + one (Sb, m) request slice stay VMEM-resident."""
-    n = t_ref.shape[1]
+    """Grid (seed block, request block). Nodes lie on sublanes and seeds on
+    lanes, so request ``i`` of the block is the leading index of every
+    stream: a (1, S) arrival row and (m, S) mask/service tiles. The queue
+    state lives in the dep/busy output blocks, which stay VMEM-resident
+    across the request axis (their block index ignores it)."""
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        dep_ref[...] = d0_ref[...]
+        busy_ref[...] = b0_ref[...]
 
     def body(i, carry):
         dep, busy = carry
-        tt = pl.load(t_ref, (slice(None), pl.ds(i, 1)))[:, 0]
-        mask = pl.load(m_ref, (slice(None), pl.ds(i, 1), slice(None)))[:, 0, :] != 0
-        srv = pl.load(s_ref, (slice(None), pl.ds(i, 1), slice(None)))[:, 0, :]
-        new_dep, new_busy, lat = _step(dep, busy, tt, mask, srv)
-        pl.store(lat_ref, (slice(None), pl.ds(i, 1)), lat[:, None])
+        new_dep, new_busy, lat = _step(
+            dep, busy, t_ref[i], m_ref[i] != 0, s_ref[i], axis=0
+        )
+        lat_ref[i] = lat
         return new_dep, new_busy
 
-    dep, busy = jax.lax.fori_loop(0, n, body, (d0_ref[...], b0_ref[...]))
+    dep, busy = jax.lax.fori_loop(
+        0, t_ref.shape[0], body, (dep_ref[...], busy_ref[...])
+    )
     dep_ref[...] = dep
     busy_ref[...] = busy
 
 
-@functools.partial(jax.jit, static_argnames=("block_seeds", "interpret"))
+_LANES = 128
+# double-buffered request streams of one grid step; well inside the 16 MiB
+# of scoped VMEM a v5e kernel gets by default
+_VMEM_BUDGET = 8 * 2**20
+
+
+def _block_requests(n: int, m: int, lanes: int) -> int:
+    """Requests per grid step: as many as the VMEM budget holds, evened out
+    so the padded horizon wastes less than one step per block."""
+    rows = 2 * 8 + 2 * (-(-m // 8) * 8)  # t + latency, mask + service
+    per_request = 2 * 4 * rows * (-(-lanes // _LANES) * _LANES)
+    cap = max(8, _VMEM_BUDGET // per_request)
+    blocks = -(-n // cap)
+    return -(-n // blocks)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def fcfs_scan_pallas(
     t: Array,
     masks: Array,
@@ -112,59 +139,53 @@ def fcfs_scan_pallas(
     dep0: Array,
     busy0: Array,
     *,
-    block_seeds: int = 8,
     interpret: bool = False,
 ) -> tuple[Array, Array, Array]:
-    """Fused FCFS scan over a seed batch: one kernel launch per seed block.
+    """Fused FCFS scan over a seed batch in one kernel launch.
 
     Shapes: ``t`` (S, N), ``masks`` (S, N, m) bool/int, ``service``
     (S, N, m), ``dep0``/``busy0`` (S, m). Returns ``(latency (S, N),
-    dep (S, m), busy (S, m))``. The seed axis is padded up to a block
-    multiple (padded rows scan zeros and are sliced away); VMEM per grid
-    step is ``Sb*N*(1 + 2m)`` values — the request streams of one seed
-    block — so callers bound N per call (the chunked-horizon driver in
-    `storage/simulator.py` feeds fixed-size blocks).
+    dep (S, m), busy (S, m))``. Inside, the streams are laid out request-
+    major with seeds on lanes ((N, m, S)); seeds beyond 128 are padded to
+    128-lane blocks, and the request axis is cut into blocks sized by
+    :func:`_block_requests`, so VMEM does not grow with the horizon.
+    Padded requests carry an empty mask and leave the queues untouched.
     """
     t = jnp.asarray(t, jnp.float32)
     service = jnp.asarray(service, jnp.float32)
-    masks = jnp.asarray(masks, jnp.uint8)
+    masks = jnp.asarray(masks, jnp.int32)
+    dep0 = jnp.asarray(dep0, jnp.float32)
+    busy0 = jnp.asarray(busy0, jnp.float32)
     s, n = t.shape
     m = service.shape[-1]
-    sb = min(block_seeds, s)
-    pad = (-s) % sb
-    if pad:
-        t = jnp.pad(t, ((0, pad), (0, 0)))
-        masks = jnp.pad(masks, ((0, pad), (0, 0), (0, 0)))
-        service = jnp.pad(service, ((0, pad), (0, 0), (0, 0)))
-        dep0 = jnp.pad(jnp.asarray(dep0, jnp.float32), ((0, pad), (0, 0)))
-        busy0 = jnp.pad(jnp.asarray(busy0, jnp.float32), ((0, pad), (0, 0)))
-    sp = s + pad
+    sl = s if s <= _LANES else _LANES
+    sp = -(-s // sl) * sl
+    bn = _block_requests(n, m, sl)
+    np_ = -(-n // bn) * bn
+    pad_s, pad_n = sp - s, np_ - n
+    t_k = jnp.pad(t.T, ((0, pad_n), (0, pad_s)))[:, None, :]  # (N, 1, S)
+    m_k = jnp.pad(masks.transpose(1, 2, 0), ((0, pad_n), (0, 0), (0, pad_s)))
+    s_k = jnp.pad(service.transpose(1, 2, 0), ((0, pad_n), (0, 0), (0, pad_s)))
+    d_k = jnp.pad(dep0.T, ((0, 0), (0, pad_s)))  # (m, S)
+    b_k = jnp.pad(busy0.T, ((0, 0), (0, pad_s)))
+    req = lambda rows: pl.BlockSpec((bn, rows, sl), lambda i, j: (j, 0, i))
+    state = pl.BlockSpec((m, sl), lambda i, j: (0, i))
     latency, dep, busy = pl.pallas_call(
         _fcfs_kernel,
-        grid=(sp // sb,),
-        in_specs=[
-            pl.BlockSpec((sb, n), lambda i: (i, 0)),
-            pl.BlockSpec((sb, n, m), lambda i: (i, 0, 0)),
-            pl.BlockSpec((sb, n, m), lambda i: (i, 0, 0)),
-            pl.BlockSpec((sb, m), lambda i: (i, 0)),
-            pl.BlockSpec((sb, m), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((sb, n), lambda i: (i, 0)),
-            pl.BlockSpec((sb, m), lambda i: (i, 0)),
-            pl.BlockSpec((sb, m), lambda i: (i, 0)),
-        ],
+        grid=(sp // sl, np_ // bn),
+        in_specs=[req(1), req(m), req(m), state, state],
+        out_specs=[req(1), state, state],
         out_shape=[
-            jax.ShapeDtypeStruct((sp, n), jnp.float32),
-            jax.ShapeDtypeStruct((sp, m), jnp.float32),
-            jax.ShapeDtypeStruct((sp, m), jnp.float32),
+            jax.ShapeDtypeStruct((np_, 1, sp), jnp.float32),
+            jax.ShapeDtypeStruct((m, sp), jnp.float32),
+            jax.ShapeDtypeStruct((m, sp), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(
-        t, masks, service,
-        jnp.asarray(dep0, jnp.float32), jnp.asarray(busy0, jnp.float32),
-    )
-    return latency[:s], dep[:s], busy[:s]
+    )(t_k, m_k, s_k, d_k, b_k)
+    return latency[:n, 0, :s].T, dep[:, :s].T, busy[:, :s].T
 
 
 def fcfs_scan(

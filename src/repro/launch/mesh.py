@@ -8,23 +8,20 @@ from __future__ import annotations
 import jax
 
 
+def _auto(n: int) -> tuple:
+    """GSPMD-style axes: the model stack shards by constraints, not by type."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_local_mesh():
     """Whatever devices exist locally, as a ('data','model') mesh with
     model=1 — used by tests and CPU examples."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
-
-
-def set_mesh(mesh):
-    """Context manager activating ``mesh``, across JAX versions.
-
-    Newer JAX spells this ``jax.set_mesh(mesh)``; on older releases the
-    ``Mesh`` object itself is the context manager."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    return jax.make_mesh((n, 1), ("data", "model"), axis_types=_auto(2))

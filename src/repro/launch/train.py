@@ -25,7 +25,7 @@ import numpy as np
 from repro.checkpoint import ECCheckpointStore, plan_for_params
 from repro.configs.registry import get_config, get_smoke_config
 from repro.data.pipeline import SyntheticLM
-from repro.launch.mesh import make_local_mesh, set_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import TrainState, build_model, jit_train_step
 from repro.optim import AdamW, compress_decompress, compress_init, cosine_schedule
 from repro.storage import tahoe_testbed
@@ -54,7 +54,7 @@ def train(
     data = SyntheticLM(vocab=cfg.vocab, seq_len=seq, global_batch=batch)
 
     batch_sds = {"tokens": jax.ShapeDtypeStruct((batch, seq), jnp.int32)}
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn, abstract, state_sh, batch_sh = jit_train_step(model, opt, mesh, batch_sds)
 
         params = model.init(jax.random.key(0))

@@ -35,22 +35,6 @@ from .config import ModelConfig, MoEConfig
 from .layers import _init, mlp_apply, mlp_init
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """`jax.shard_map` across JAX versions (older releases expose it as
-    `jax.experimental.shard_map.shard_map` with `check_rep` instead of
-    `check_vma`)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
 Params = dict[str, Any]
 
 
@@ -217,7 +201,7 @@ def moe_apply(
             }
         else:
             shared, shared_spec = {}, {}
-        y2d, aux = _shard_map(
+        y2d, aux = jax.shard_map(
             island_tiny,
             mesh=mesh,
             in_specs=(
@@ -262,7 +246,7 @@ def moe_apply(
         shared = {}
         shared_spec = {}
 
-    y2d, aux = _shard_map(
+    y2d, aux = jax.shard_map(
         island,
         mesh=mesh,
         in_specs=(

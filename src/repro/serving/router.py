@@ -431,8 +431,6 @@ def _arbitrate_device(
     b = pi_stack.shape[0]
     k = keys.shape[0]
     if shard:
-        from repro.storage.simulator import _shard_map_compat
-
         lanes_pi = jnp.repeat(pi_stack, k, axis=0)  # (B*K, r, m)
         lanes_key = jnp.broadcast_to(keys[None], (b, k)).reshape(-1)
         mesh = jax.sharding.Mesh(np.asarray(jax.devices()), ("cand",))
@@ -445,11 +443,14 @@ def _arbitrate_device(
                 )
             )(kl, pl)
 
-        lane_scores = _shard_map_compat()(
+        # lanes never communicate: collective-free body, so no varying-
+        # axis types (see simulate_fleet)
+        lane_scores = jax.shard_map(
             lanes_fn,
             mesh=mesh,
             in_specs=(pspec("cand"), pspec("cand")) + (pspec(),) * 8,
             out_specs=pspec("cand"),
+            check_vma=False,
         )(
             lanes_key, lanes_pi, carry, lam, overheads, rates, avail,
             ttl, hit_latency, spec,

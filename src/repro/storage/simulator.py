@@ -1014,7 +1014,7 @@ class FleetResult(NamedTuple):
 
 
 def _fleet_inputs(key, pi, lam_cs, overheads_cs, rates_cs, n_requests, ttl,
-                  t0=0.0, cache=None):
+                  cache=None):
     """One seed's merged request stream: arrivals, marks, service draws,
     Madow service sets, and (when a hot tier is simulated) cache hits
     thinned out of the dispatch masks. Vmapped over the seed axis by every
@@ -1022,8 +1022,7 @@ def _fleet_inputs(key, pi, lam_cs, overheads_cs, rates_cs, n_requests, ttl,
     `kernels/fcfs_queue.py` scan afterwards."""
     m = overheads_cs.shape[-1]
     k_wl, k_sel, k_srv = jax.random.split(key, 3)
-    rel, file_id, site_id = generate_geo_workload(k_wl, lam_cs, n_requests)
-    t = t0 + rel
+    t, file_id, site_id = generate_geo_workload(k_wl, lam_cs, n_requests)
     sel_keys = jax.random.split(k_sel, n_requests)
     e = jax.random.exponential(k_srv, (n_requests, m))
     service = overheads_cs[site_id] + e / rates_cs[site_id]
@@ -1117,12 +1116,17 @@ def _fleet_stream_batched(
 ):
     """Streaming fleet: scan over ``n_chunks`` fixed-size request blocks.
 
-    Carry = FCFS queue state + accrued busy + absolute clock + cache
+    Carry = FCFS queue state + accrued busy + clock origin + cache
     warmth + the :class:`~.streaming.StreamingStats` accumulators, all
     (S,)-batched — so memory is O(S * block), constant in the total
     horizon ``n_chunks * block``. Each chunk draws its own workload block
-    (arrivals continue from the carried clock — one continuous system
-    history per seed, the same contract as ``SimCarry``), runs the
+    and continues one system history per seed (the same contract as
+    ``SimCarry``) on a re-based clock: chunk arrivals count from the
+    previous chunk's last arrival, and the carried departure and cache
+    expiry times shift by that origin on entry. A float32 clock thus
+    keeps the resolution of one chunk's span however long the horizon
+    (an absolute clock loses it: at ~10^5 s its ulp reaches the
+    smallest arrival gaps and arrivals stop increasing). It runs the
     (S, m)-wide FCFS kernel, and folds the block's latencies into both
     the global accumulators and that chunk's *window* stats (the
     streaming `p99_windowed` surface). With ``n_chunks == 1`` the random
@@ -1145,14 +1149,14 @@ def _fleet_stream_batched(
     ttl_arr = ttl if cached else None
 
     def chunk_step(carry, ckeys):
-        dep, busy, t0, cache, stats, hitcnt, idx0 = carry
-        prep = lambda k, tt0, ca: _fleet_inputs(
-            k, pi, lam_cs, overheads_cs, rates_cs, block, ttl_arr,
-            t0=tt0, cache=ca,
+        dep, busy, origin, cache, stats, hitcnt, idx0 = carry
+        dep = dep - origin[:, None]
+        if cached:
+            cache = cache - origin[:, None]
+        prep = lambda k, ca: _fleet_inputs(
+            k, pi, lam_cs, overheads_cs, rates_cs, block, ttl_arr, cache=ca
         )
-        t, _, _, masks, service, hit, new_cache = jax.vmap(prep)(
-            ckeys, t0, cache
-        )
+        t, _, _, masks, service, hit, new_cache = jax.vmap(prep)(ckeys, cache)
         latency, dep, busy = fcfs_scan(
             t, masks, service, dep, busy, backend=backend
         )
@@ -1175,7 +1179,7 @@ def _fleet_stream_batched(
     carry0 = (
         jnp.zeros((s, m)),  # dep
         jnp.zeros((s, m)),  # busy
-        jnp.zeros((s,)),  # absolute clock
+        jnp.zeros((s,)),  # clock origin of the next chunk
         jnp.full((s, r), -jnp.inf) if cached else None,  # cache warmth
         stream_init(sketch, (s,)),
         jnp.zeros((s,), jnp.int32) if cached else None,
@@ -1190,15 +1194,6 @@ def _fleet_stream_batched(
     if materialize:
         lats = jnp.swapaxes(lats, 0, 1).reshape(s, n_chunks * block)
     return stats, windows, busy, hitcnt, lats
-
-
-def _shard_map_compat():
-    """`jax.shard_map` across the JAX versions this repo supports."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm
 
 
 @diag.hot_path("storage.simulate_fleet")
@@ -1301,11 +1296,16 @@ def simulate_fleet(
             keys = keys[jnp.arange(s_run) % n_seeds]
         mesh = jax.sharding.Mesh(np.asarray(jax.devices()), ("seed",))
         spec = jax.sharding.PartitionSpec
-        sharded = _shard_map_compat()(
+        # seeds never communicate, so the body is collective-free and
+        # needs no varying-axis types; with them, every scan carry built
+        # inside (queues, clock, cache warmth) would have to be cast to
+        # "varying" by hand to match its per-seed outputs
+        sharded = jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(spec("seed"),) + (spec(),) * 6,
             out_specs=spec("seed"),
+            check_vma=False,
         )
         out = sharded(keys, jnp.asarray(pi), lam_cs, d, rates, ttl, hit_lat)
         if s_run != n_seeds:

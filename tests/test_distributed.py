@@ -18,8 +18,8 @@ from repro.launch.roofline import (
     fused_hbm_bytes,
 )
 
-MESH_1POD = AbstractMesh((("data", 16), ("model", 16)))
-MESH_2POD = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH_1POD = AbstractMesh((16, 16), ("data", "model"))
+MESH_2POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class _Key:
@@ -123,13 +123,15 @@ def test_mini_dryrun_on_8_fake_devices(tmp_path):
         from repro.launch.steps import build_model, jit_train_step
         from repro.optim import AdamW
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh(
+            (4, 2), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        )
         cfg = get_smoke_config("qwen3-moe-30b-a3b")  # exercises the EP island
         model = build_model(cfg, mesh, dtype=jnp.float32, remat="none")
         batch_sds = {"tokens": jax.ShapeDtypeStruct((8, 16), jnp.int32)}
         step, abstract, state_sh, batch_sh = jit_train_step(model, AdamW(), mesh, batch_sds)
-        from repro.launch.mesh import set_mesh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = step.lower(abstract, batch_sds).compile()
             from repro.launch.roofline import first_cost_analysis
             ca = first_cost_analysis(compiled)

@@ -106,7 +106,7 @@ def test_opt_levels_monotone_features():
 def test_build_model_pin_wiring():
     from jax.sharding import AbstractMesh
 
-    mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     m = build_model(get_config("smollm-135m"), mesh, opt="O2")
     assert m.pin_axes == ("data",)
     m0 = build_model(get_config("smollm-135m"), mesh, opt="O0")

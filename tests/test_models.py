@@ -234,12 +234,14 @@ def test_moe_ep_paths_match_local_oracle():
         from repro.models import EPSpec
         from repro.models.moe import moe_apply, moe_init
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh(
+            (4, 2), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        )
         cfg = get_smoke_config("deepseek-v3-671b")
         p = moe_init(jax.random.key(0), cfg, jnp.float32)
         ep = EPSpec(mesh=mesh, ep_axis="model", fsdp_axes=("data",), dp_axes=("data",))
-        from repro.launch.mesh import set_mesh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             for shape in ((8, 1), (8, 300)):  # tiny (resident) + big (ZeRO)
                 x = jax.random.normal(jax.random.key(1), shape + (cfg.d_model,)) * 0.3
                 y_ref, _ = moe_apply(p, x, cfg)

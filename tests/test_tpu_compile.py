@@ -1,0 +1,158 @@
+"""Ahead-of-time compiles of the main path's kernels for a TPU v5e.
+
+The TPU compiler is installed with JAX and compiles for a chip that is
+described, not attached, so these tests catch what interpret mode hides:
+Mosaic refusing an unaligned or dynamic lane access, a kernel that asks
+for more VMEM than a v5e grants, a program that does not fit 16 GB of
+HBM. Nothing runs; results are checked by the interpret-mode parity
+tests (`test_fleet_parity.py`, `test_kernels.py`).
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library, and a test worker that cannot
+skips these tests there instead of failing collection everywhere.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.fcfs_queue import fcfs_scan_pallas
+from repro.kernels.gf256_matmul import (
+    gf256_matmul_pallas,
+    gf256_matmul_pallas_batched,
+    select_block_sizes,
+)
+from repro.kernels.ops import gf256_matmul_batch_bitplane
+from repro.storage import encode_batch
+
+HBM_BYTES = 16 * 10**9  # one v5e chip
+M = 12  # nodes of the Tahoe testbed
+K = 6  # RS data chunks of the paper's objects
+CHUNK = 150 * 10**6 // K  # one chunk of a 150 MB object
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    """ShapeDtypeStruct factory placed on one described v5e chip."""
+    one_chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """A compile for a described chip cannot be read back without one, so
+    keep it out of the persistent cache (and the cache's warnings out)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+def _fcfs(t, masks, service, dep0, busy0):
+    return fcfs_scan_pallas(t, masks, service, dep0, busy0, interpret=False)
+
+
+@pytest.mark.parametrize(
+    "s,n",
+    [(1, 20000), (32, 2048)],
+    ids=["simulate", "fleet-chunk"],
+)
+def test_fcfs_kernel_compiles_for_v5e(sds, s, n):
+    f32 = jnp.float32
+    compiled = (
+        jax.jit(_fcfs)
+        .lower(
+            sds((s, n), f32), sds((s, n, M), jnp.bool_), sds((s, n, M), f32),
+            sds((s, M), f32), sds((s, M), f32),
+        )
+        .compile()
+    )
+    assert _has_kernel(compiled)
+
+
+def test_fcfs_kernel_compiles_under_rollout_vmap(sds):
+    """The arbitration's (candidate, seed) lanes vmap a single-system scan."""
+    f32 = jnp.float32
+    b, k, n = 8, 2, 600
+
+    def one(t, masks, service, dep0):
+        lat, _, _ = _fcfs(
+            t[None], masks[None], service[None], dep0[None], jnp.zeros_like(dep0)[None]
+        )
+        return lat[0]
+
+    compiled = (
+        jax.jit(jax.vmap(jax.vmap(one)))
+        .lower(
+            sds((b, k, n), f32), sds((b, k, n, M), jnp.bool_),
+            sds((b, k, n, M), f32), sds((b, k, M), f32),
+        )
+        .compile()
+    )
+    assert _has_kernel(compiled)
+
+
+def test_gf256_encode_kernel_compiles_for_v5e(sds):
+    """Parity rows of one object: (n-k, k) @GF (k, chunk bytes)."""
+    u8 = jnp.uint8
+    bm, bn, bk = select_block_sizes(3, CHUNK, K)
+    f = lambda a, b: gf256_matmul_pallas(
+        a, b, block_m=bm, block_n=bn, block_k=bk, interpret=False
+    )
+    compiled = jax.jit(f).lower(sds((3, K), u8), sds((K, CHUNK), u8)).compile()
+    assert _has_kernel(compiled)
+
+
+def test_gf256_batched_decode_kernel_compiles_for_v5e(sds):
+    """Degraded reads of a few objects: (B, k, k) @GF (B, k, chunk bytes)."""
+    u8 = jnp.uint8
+    b = 4
+    f = lambda a, c: gf256_matmul_pallas_batched(a, c, interpret=False)
+    compiled = jax.jit(f).lower(sds((b, K, K), u8), sds((b, K, CHUNK), u8)).compile()
+    assert _has_kernel(compiled)
+
+
+def test_bitplane_decode_of_one_object_fits_v5e(sds):
+    u8 = jnp.uint8
+    compiled = (
+        jax.jit(gf256_matmul_batch_bitplane)
+        .lower(sds((1, K, K), u8), sds((1, K, CHUNK), u8))
+        .compile()
+    )
+    ma = compiled.memory_analysis()
+    total = ma.temp_size_in_bytes + ma.argument_size_in_bytes + ma.output_size_in_bytes
+    assert total < HBM_BYTES, total
+
+
+def test_bitplane_encode_of_objects_fits_v5e(sds):
+    """Parity of several objects at once: one batched matmul, no transpose
+    of the batch into the byte axis (that relayout stalled the compiler)."""
+    b = 4
+    compiled = (
+        jax.jit(lambda d: encode_batch(d, 2 * K, backend="bitplane"))
+        .lower(sds((b, K, CHUNK), jnp.uint8))
+        .compile()
+    )
+    ma = compiled.memory_analysis()
+    total = ma.temp_size_in_bytes + ma.argument_size_in_bytes + ma.output_size_in_bytes
+    assert total < HBM_BYTES, total
