@@ -277,53 +277,54 @@ def _device_merged_loop(
         return jnp.logical_and(s.t < max_iters, jnp.logical_not(s.done))
 
     def body(s: _LoopState) -> _LoopState:
-        g = _merged_grad(s.pi, s.z, prob, beta)
+        with diag.scope("jlcm.iterate"):
+            g = _merged_grad(s.pi, s.z, prob, beta)
 
-        def attempt(step_lr):
-            p = project_capped_simplex(s.pi - step_lr * g, prob.k, mask)
-            zz = _refresh_z(p, prob)
-            return p, zz, smoothed_objective(p, zz, prob, beta)
+            def attempt(step_lr):
+                p = project_capped_simplex(s.pi - step_lr * g, prob.k, mask)
+                zz = _refresh_z(p, prob)
+                return p, zz, smoothed_objective(p, zz, prob, beta)
 
-        def backtrack(_):
-            second = attempt(s.lr / 4.0)
-            return jax.lax.cond(
-                second[2] > s.prev + BACKTRACK_SLACK,
-                lambda _: attempt(s.lr / 16.0),
-                lambda _: second,
-                None,
+            def backtrack(_):
+                second = attempt(s.lr / 4.0)
+                return jax.lax.cond(
+                    second[2] > s.prev + BACKTRACK_SLACK,
+                    lambda _: attempt(s.lr / 16.0),
+                    lambda _: second,
+                    None,
+                )
+
+            first = attempt(s.lr)
+            cand = jax.lax.cond(
+                first[2] > s.prev + BACKTRACK_SLACK, backtrack, lambda _: first, None
             )
 
-        first = attempt(s.lr)
-        cand = jax.lax.cond(
-            first[2] > s.prev + BACKTRACK_SLACK, backtrack, lambda _: first, None
-        )
-
-        accepted = cand[2] <= s.prev + BACKTRACK_SLACK
-        pi_n = jnp.where(accepted, cand[0], s.pi)
-        z_n = jnp.where(accepted, cand[1], s.z)
-        obj = jnp.where(accepted, cand[2], s.prev)  # stalled step keeps prev
-        # a rejected round already probed {lr, lr/4, lr/16}, so shrinking
-        # 16x continues the geometric /4 probe grid with nothing skipped —
-        # and a warm start at a converged point collapses in ~4 rounds
-        # instead of ~40 halvings
-        lr_n = jnp.where(accepted, jnp.minimum(s.lr * 1.1, lr_cap), s.lr / 16.0)
-        collapsed = jnp.logical_and(~accepted, lr_n <= lr_cap * 1e-6)
-        # relative stopping rule (paper: tolerance on normalized objective);
-        # a rejected step only stops once lr has collapsed — otherwise it
-        # shrinks lr and retries (obj == prev would trip the eps test).
-        converged = jnp.logical_and(
-            accepted,
-            jnp.abs(s.prev - obj) < eps * jnp.maximum(1.0, jnp.abs(obj)),
-        )
-        return _LoopState(
-            pi=pi_n,
-            z=z_n,
-            prev=obj,
-            lr=lr_n,
-            t=s.t + 1,
-            done=jnp.logical_or(collapsed, converged),
-            trace=s.trace.at[s.t + 1].set(obj),
-        )
+            accepted = cand[2] <= s.prev + BACKTRACK_SLACK
+            pi_n = jnp.where(accepted, cand[0], s.pi)
+            z_n = jnp.where(accepted, cand[1], s.z)
+            obj = jnp.where(accepted, cand[2], s.prev)  # stalled step keeps prev
+            # a rejected round already probed {lr, lr/4, lr/16}, so shrinking
+            # 16x continues the geometric /4 probe grid with nothing skipped —
+            # and a warm start at a converged point collapses in ~4 rounds
+            # instead of ~40 halvings
+            lr_n = jnp.where(accepted, jnp.minimum(s.lr * 1.1, lr_cap), s.lr / 16.0)
+            collapsed = jnp.logical_and(~accepted, lr_n <= lr_cap * 1e-6)
+            # relative stopping rule (paper: tolerance on normalized objective);
+            # a rejected step only stops once lr has collapsed — otherwise it
+            # shrinks lr and retries (obj == prev would trip the eps test).
+            converged = jnp.logical_and(
+                accepted,
+                jnp.abs(s.prev - obj) < eps * jnp.maximum(1.0, jnp.abs(obj)),
+            )
+            return _LoopState(
+                pi=pi_n,
+                z=z_n,
+                prev=obj,
+                lr=lr_n,
+                t=s.t + 1,
+                done=jnp.logical_or(collapsed, converged),
+                trace=s.trace.at[s.t + 1].set(obj),
+            )
 
     out = jax.lax.while_loop(cond, body, state)
     return out.pi, out.z, out.trace, out.t
@@ -391,7 +392,8 @@ def _solve_merged_device(pi0, prob, mask, beta, lr, eps, max_iters):
     pi, z, trace, iters = _device_merged_loop(
         pi0, prob, mask, beta, lr, eps, max_iters
     )
-    return _finalize(pi, z, prob, trace), iters
+    with diag.scope("jlcm.finalize"):
+        return _finalize(pi, z, prob, trace), iters
 
 
 @functools.partial(jax.jit, static_argnames=("max_iters",))
@@ -400,7 +402,8 @@ def _solve_merged_device_batch(pi0, prob, mask, beta, lr, eps, max_iters):
         pi, z, trace, iters = _device_merged_loop(
             p0, pr, mk, beta, lr, eps, max_iters
         )
-        return _finalize(pi, z, pr, trace), iters
+        with diag.scope("jlcm.finalize"):
+            return _finalize(pi, z, pr, trace), iters
 
     return jax.vmap(one)(pi0, prob, mask)
 
@@ -717,30 +720,32 @@ def solve_batch(
     what-if re-optimization (e.g. one re-plan per hypothetical node
     failure): hundreds of solver instances become one XLA program.
     """
-    stacked = probs if isinstance(probs, JLCMProblem) else stack_problems(probs)
-    if stacked.mask is None:
-        raise ValueError("stacked problems must carry an explicit mask")
-    mask = jnp.asarray(stacked.mask, bool)
-    if pi0 is None:
-        pi0 = feasible_uniform(mask, stacked.k)
-    else:
-        pi0 = jnp.asarray(pi0)
-        if pi0.shape not in (mask.shape, mask.shape[1:]):
-            raise ValueError(
-                f"pi0 shape {pi0.shape} matches neither the stacked batch "
-                f"{tuple(mask.shape)} nor a shared per-instance start "
-                f"{tuple(mask.shape[1:])}"
-            )
-    pi0 = jnp.broadcast_to(jnp.asarray(pi0), mask.shape)
-    sol, iters = _solve_merged_device_batch(
-        pi0,
-        stacked._replace(mask=None),
-        mask,
-        jnp.asarray(beta, jnp.float32),
-        jnp.asarray(lr, jnp.float32),
-        jnp.asarray(eps, jnp.float32),
-        max_iters,
-    )
+    with diag.span("solve.stack"):
+        stacked = probs if isinstance(probs, JLCMProblem) else stack_problems(probs)
+        if stacked.mask is None:
+            raise ValueError("stacked problems must carry an explicit mask")
+        mask = jnp.asarray(stacked.mask, bool)
+        if pi0 is None:
+            pi0 = feasible_uniform(mask, stacked.k)
+        else:
+            pi0 = jnp.asarray(pi0)
+            if pi0.shape not in (mask.shape, mask.shape[1:]):
+                raise ValueError(
+                    f"pi0 shape {pi0.shape} matches neither the stacked batch "
+                    f"{tuple(mask.shape)} nor a shared per-instance start "
+                    f"{tuple(mask.shape[1:])}"
+                )
+        pi0 = jnp.broadcast_to(jnp.asarray(pi0), mask.shape)
+    with diag.span("solve.dispatch"):
+        sol, iters = _solve_merged_device_batch(
+            pi0,
+            stacked._replace(mask=None),
+            mask,
+            jnp.asarray(beta, jnp.float32),
+            jnp.asarray(lr, jnp.float32),
+            jnp.asarray(eps, jnp.float32),
+            max_iters,
+        )
     return sol._replace(iterations=iters)
 
 

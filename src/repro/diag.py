@@ -1,4 +1,19 @@
-"""Runtime hot-path guards: host-sync tripwires + compile-reuse watchers.
+"""Runtime diagnostics: spans and counters on the profiler's clock,
+host-sync tripwires and compile-reuse watchers.
+
+**Spans and counters.** :class:`span` times a region on the host
+(``perf_counter``, exposed as ``.seconds`` for the program's own
+telemetry) and writes it into the JAX profiler's trace as a
+``jax.profiler.TraceAnnotation``: under an active trace
+(``jax.profiler.trace(dir)``) it lands on ``/host:CPU`` on the same clock
+as the device planes; with no trace active it costs about a microsecond
+and leaves nothing behind. :func:`count` writes an instant annotation
+with a ``value`` stat, a counter attributable to the span around it.
+:func:`scope` names a region of device code, so each XLA operation's
+device time can be read by the scope it came from.
+Every program span name carries a dotted layer prefix (``replan.solve``,
+``codec.wait``); ``docs/diagnostics.md`` lists them all and what each
+answers.
 
 The closed loop only hits its latency targets while two contracts hold:
 
@@ -14,12 +29,12 @@ The closed loop only hits its latency targets while two contracts hold:
 *runtime* half: guards that make a violated contract fail loudly in a
 live run instead of silently costing milliseconds per segment.
 
-Everything here is inert unless ``REPRO_DIAG=1`` (checked per call, so a
-test can flip it with ``monkeypatch.setenv``): the :func:`hot_path`
-wrapper costs one ``os.environ`` lookup when disabled.
+The guards are inert unless ``REPRO_DIAG=1`` (checked per call, so a
+test can flip it with ``monkeypatch.setenv``): disarmed, the
+:func:`hot_path` wrapper costs one ``os.environ`` lookup and its span.
 
 Guard mechanics (:func:`hot_path`, usable as decorator or context
-manager):
+manager; it always opens a :class:`span` under its label):
 
 * ``jax.transfer_guard_device_to_host("disallow")`` — the real device
   guard. On an accelerator every implicit device->host readback inside
@@ -48,18 +63,23 @@ import dataclasses
 import functools
 import os
 import threading
+import time
 from typing import Any, Callable
 
 import jax
 import numpy as np
+from jax.experimental.xla_metadata import set_xla_metadata
 
 __all__ = [
     "CompileWatcher",
     "HostSyncError",
     "RecompileError",
+    "count",
     "enabled",
     "hot_path",
     "hot_path_registry",
+    "scope",
+    "span",
 ]
 
 
@@ -83,6 +103,60 @@ def enabled() -> bool:
 
 
 # ---------------------------------------------------------------------------
+# Spans and counters on the profiler's clock.
+# ---------------------------------------------------------------------------
+
+
+class span:
+    """Time a region on the host and write it into the profiler's trace.
+
+    ``with span("replan.solve") as sp: ...`` leaves the region's host
+    seconds in ``sp.seconds``; under an active profiler trace the region
+    also appears on ``/host:CPU`` as an event named ``name`` whose stats
+    are ``stats`` (``span("codec.to_host", bytes=n)``).
+    """
+
+    __slots__ = ("name", "stats", "seconds", "_ann", "_t0")
+
+    def __init__(self, name: str, **stats: Any):
+        self.name = name
+        self.stats = stats
+        self.seconds = 0.0
+
+    def __enter__(self) -> "span":
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.stats)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+@contextlib.contextmanager
+def scope(name: str):
+    """Name a region of device code: every operation traced inside carries
+    ``name`` in its scope path (``jax.named_scope``; on a TPU trace the
+    ``tf_op`` stat of the operation's metadata) and in an XLA frontend
+    attribute, ``repro_scope``. The persistent compilation cache keys on
+    the program with its debug metadata stripped, so a scope path alone
+    would not tell an executable compiled before the scope existed from
+    one compiled after; the attribute is part of the program and does.
+    Neither changes the compiled operations."""
+    with jax.named_scope(name), set_xla_metadata(repro_scope=name):
+        yield
+
+
+def count(name: str, value: int | float) -> None:
+    """Write counter ``name`` with ``value`` into the profiler's trace: an
+    instant event with a ``value`` stat, inside whatever span is open."""
+    with jax.profiler.TraceAnnotation(name, value=value):
+        pass
+
+
+# ---------------------------------------------------------------------------
 # Hot-path registry: the names `tools/jaxcheck` treats as device hot paths.
 # ---------------------------------------------------------------------------
 
@@ -92,10 +166,10 @@ _LOCK = threading.Lock()
 
 @dataclasses.dataclass
 class HotPathStats:
-    """Per-label call accounting for a registered hot path."""
+    """Per-label guard accounting for a registered hot path (each call is
+    a span of the same label in the profiler's trace)."""
 
     label: str
-    calls: int = 0
     guarded_calls: int = 0
     recompiles: int = 0  # cache growth observed after the warmup call
     _sizes: dict[int, int] = dataclasses.field(default_factory=dict)
@@ -189,8 +263,8 @@ class _HotPathGuard:
     # -- context-manager protocol ------------------------------------
     def __enter__(self):
         stats = _stats(self.label)
-        stats.calls += 1
         stack = contextlib.ExitStack()
+        stack.enter_context(span(self.label))
         if enabled():
             stats.guarded_calls += 1
             stack.enter_context(

@@ -80,6 +80,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import diag
 from repro.core import (
     Hierarchy,
     JLCMProblem,
@@ -575,40 +576,44 @@ def run_scenario(
                 and np.array_equal(avail_tr[s], repair_avail)
                 else None
             )
-            t_start = 0.0 if carry is None else float(carry.t0)
-            res_s, carry = simulate_segment(
-                seg_keys[s],
-                jnp.asarray(seg_pi(pi, s, rep_s)),
-                lam_sim,
-                cluster,
-                spec.chunk_mb,
-                n_req,
-                avail=avail_tr[s],
-                rate_scale=seg_scale(s),
-                overhead_scale=ovh_tr[s],
-                bandwidth_scale=bw_tr[s],
-                carry=carry,
-                cache_ttl=(
-                    np.where(cache_up[s], ttl_cur, 0.0)
-                    if has_cache
-                    else None
-                ),
-                cache_hit_latency=spec.cache_hit_latency,
-            )
-            moment_est.update(res_s.obs)
-            fid_s = np.asarray(res_s.file_id)
-            client_s = fid_s < r
-            dur = float(res_s.t_end) - t_start
-            if has_cache:
-                hit_s = np.asarray(res_s.hit)
-                rate_est.update_misses(
-                    fid_s[client_s], hit_s[client_s], dur
+            with diag.span("loop.simulate"):
+                t_start = 0.0 if carry is None else float(carry.t0)
+                res_s, carry = simulate_segment(
+                    seg_keys[s],
+                    jnp.asarray(seg_pi(pi, s, rep_s)),
+                    lam_sim,
+                    cluster,
+                    spec.chunk_mb,
+                    n_req,
+                    avail=avail_tr[s],
+                    rate_scale=seg_scale(s),
+                    overhead_scale=ovh_tr[s],
+                    bandwidth_scale=bw_tr[s],
+                    carry=carry,
+                    cache_ttl=(
+                        np.where(cache_up[s], ttl_cur, 0.0)
+                        if has_cache
+                        else None
+                    ),
+                    cache_hit_latency=spec.cache_hit_latency,
                 )
-                hits.append(hit_s)
-            else:
-                rate_est.update(fid_s[client_s], dur)
-            lats.append(np.asarray(res_s.latency))
-            degs.append(np.asarray(res_s.degraded))
+                # the segment's results to the host (pure reads: where they
+                # sit beside the estimator updates changes no value)
+                fid_s = np.asarray(res_s.file_id)
+                dur = float(res_s.t_end) - t_start
+                hit_s = np.asarray(res_s.hit) if has_cache else None
+                lats.append(np.asarray(res_s.latency))
+                degs.append(np.asarray(res_s.degraded))
+            with diag.span("loop.observe"):
+                moment_est.update(res_s.obs)
+                client_s = fid_s < r
+                if has_cache:
+                    rate_est.update_misses(
+                        fid_s[client_s], hit_s[client_s], dur
+                    )
+                    hits.append(hit_s)
+                else:
+                    rate_est.update(fid_s[client_s], dur)
             fids.append(fid_s)
             pis.append(np.asarray(pi))
         lat = np.stack(lats)
@@ -762,27 +767,28 @@ def run_geo_scenario(
                     carry=carry,
                     key=rollout_keys[s],
                 )
-            t_start = 0.0 if carry is None else float(carry.t0)
-            res_s, carry = simulate_geo_segment(
-                seg_keys[s],
-                jnp.asarray(pi),
-                lam_cs_seq[s],
-                fabric,
-                spec.chunk_mb,
-                n_req,
-                avail=avail_tr[s],
-                overhead_scale=ovh_tr[s],
-                bandwidth_scale=bw_tr[s],
-                carry=carry,
-            )
-            moment_est.update(res_s.obs)
-            fid_s = np.asarray(res_s.file_id)
-            site_s = np.asarray(res_s.site_id)
-            rate_est.update(
-                site_s * r + fid_s, float(res_s.t_end) - t_start
-            )
-            lats.append(np.asarray(res_s.latency))
-            degs.append(np.asarray(res_s.degraded))
+            with diag.span("loop.simulate"):
+                t_start = 0.0 if carry is None else float(carry.t0)
+                res_s, carry = simulate_geo_segment(
+                    seg_keys[s],
+                    jnp.asarray(pi),
+                    lam_cs_seq[s],
+                    fabric,
+                    spec.chunk_mb,
+                    n_req,
+                    avail=avail_tr[s],
+                    overhead_scale=ovh_tr[s],
+                    bandwidth_scale=bw_tr[s],
+                    carry=carry,
+                )
+                fid_s = np.asarray(res_s.file_id)
+                site_s = np.asarray(res_s.site_id)
+                dur = float(res_s.t_end) - t_start
+                lats.append(np.asarray(res_s.latency))
+                degs.append(np.asarray(res_s.degraded))
+            with diag.span("loop.observe"):
+                moment_est.update(res_s.obs)
+                rate_est.update(site_s * r + fid_s, dur)
             sites.append(site_s)
         lat = np.stack(lats)
         degraded = np.stack(degs)

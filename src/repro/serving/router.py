@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Any
 
 import jax
@@ -765,180 +764,190 @@ class AdaptiveReplanner:
         from repro.storage.cache import che_hit_rates
         from repro.storage.repair import augment_plan
 
-        r = int(np.asarray(self.k).shape[0])
-        avail = np.asarray(avail, bool)
-        masks = [avail] if candidate_masks is None else candidate_masks
-        thetas = (self.theta,) if self.thetas is None else tuple(self.thetas)
-        mom = self.estimator.moments()
-        with_repair = repair is not None and repair.active
-        k_vec = np.asarray(self.k, np.float32)
-        lam_np = np.asarray(class_rates, np.float64)
-        cache_spec = None
-        ttl_plan = None
-        if self.cache is not None:
-            # invert miss -> raw through the TTLs those misses were
-            # observed under (zeros when the tier was down: identity)
-            ttl_prev = (
-                np.zeros((r,))
-                if self.last_ttl is None
-                else np.asarray(self.last_ttl, np.float64)
-            )
-            raw = self.cache.reconstruct_raw_rates(
-                lam_np, ttl_prev, prior=self.last_raw
-            )
-            self.last_raw = raw
-            if cache_up:
-                ttl_plan = self.cache.ttl(raw)  # promotion/demotion
-                hit = che_hit_rates(raw, ttl_plan)
-                lam_np = raw
-            else:
-                ttl_plan = np.zeros((r,))
-                hit = np.zeros((r,))
-                # outage plan: full raw load plus surge head-room (the
-                # EWMA raw estimate lags the storm; see surge_margin)
-                lam_np = raw * float(self.surge_margin)
-            self.last_ttl = ttl_plan
-        if with_repair:
-            lam_np = np.concatenate([lam_np, np.asarray(repair.lam)])
-            k_vec = np.concatenate([k_vec, np.asarray(repair.k, np.float32)])
-        if self.cache is not None:
-            # repair rows join with hit 0 — reconstruction reads fetch
-            # lost chunks, which no hot tier holds
-            from repro.core import make_cache_spec
-
-            cache_spec = make_cache_spec(
-                np.concatenate([hit, np.zeros((lam_np.shape[0] - r,))]),
-                hit_latency=self.cache.hit_latency,
-                hot_cost=self.cache.hot_cost(),
-            )
-        lam = jnp.asarray(lam_np, jnp.float32)
-        objective = self._repair_objective() if with_repair else self.objective
-        probs, starts = [], []
-        for t in thetas:
-            for mk in masks:
-                mask = np.broadcast_to(
-                    np.asarray(mk, bool), (r, avail.shape[-1])
-                )
-                if with_repair:
-                    mask = np.concatenate(
-                        [mask, np.asarray(repair.mask, bool)], axis=0
+        with diag.span("replan.step"):
+            with diag.span("replan.estimate"):
+                r = int(np.asarray(self.k).shape[0])
+                avail = np.asarray(avail, bool)
+                masks = [avail] if candidate_masks is None else candidate_masks
+                thetas = (self.theta,) if self.thetas is None else tuple(self.thetas)
+                mom = self.estimator.moments()
+                with_repair = repair is not None and repair.active
+                k_vec = np.asarray(self.k, np.float32)
+                lam_np = np.asarray(class_rates, np.float64)
+                cache_spec = None
+                ttl_plan = None
+                if self.cache is not None:
+                    # invert miss -> raw through the TTLs those misses were
+                    # observed under (zeros when the tier was down: identity)
+                    ttl_prev = (
+                        np.zeros((r,))
+                        if self.last_ttl is None
+                        else np.asarray(self.last_ttl, np.float64)
                     )
-                mask = jnp.asarray(mask)
-                prob = JLCMProblem(
-                    lam=lam,
-                    k=jnp.asarray(k_vec),
-                    moments=mom,
-                    cost=jnp.asarray(self.cost, jnp.float32),
-                    theta=float(t),
-                    mask=mask,
-                    objective=objective,
-                    cache=cache_spec,
-                )
-                probs.append(prob)
-                starts.append(feasible_uniform(mask, prob.k))
-                if pi0 is not None:
-                    if with_repair:
-                        start, _ = augment_plan(pi0, lam_np[:r], repair)
+                    raw = self.cache.reconstruct_raw_rates(
+                        lam_np, ttl_prev, prior=self.last_raw
+                    )
+                    self.last_raw = raw
+                    if cache_up:
+                        ttl_plan = self.cache.ttl(raw)  # promotion/demotion
+                        hit = che_hit_rates(raw, ttl_plan)
+                        lam_np = raw
                     else:
-                        start = np.asarray(pi0)
-                    probs.append(prob)
-                    starts.append(jnp.asarray(start, jnp.float32))
-        t0 = time.perf_counter()
-        sols = solve_batch(probs, max_iters=self.max_iters, pi0=jnp.stack(starts))
-        jax.block_until_ready(sols.pi)
-        self.solve_walls.append(time.perf_counter() - t0)
-        self.replans += 1
+                        ttl_plan = np.zeros((r,))
+                        hit = np.zeros((r,))
+                        # outage plan: full raw load plus surge head-room (the
+                        # EWMA raw estimate lags the storm; see surge_margin)
+                        lam_np = raw * float(self.surge_margin)
+                    self.last_ttl = ttl_plan
+                if with_repair:
+                    lam_np = np.concatenate([lam_np, np.asarray(repair.lam)])
+                    k_vec = np.concatenate([k_vec, np.asarray(repair.k, np.float32)])
+                if self.cache is not None:
+                    # repair rows join with hit 0 — reconstruction reads fetch
+                    # lost chunks, which no hot tier holds
+                    from repro.core import make_cache_spec
 
-        if carry is not None and key is not None:
-            d, srv_rates = self.estimator.fitted_shifted_exp()
-            ttl_roll = hit_lat = None
-            if self.cache is not None:
-                # roll out with the planned TTLs so the scorer sees the
-                # same thinned queue load the solver planned for (repair
-                # rows TTL 0: never cached)
-                ttl_roll = jnp.asarray(
-                    np.concatenate(
-                        [ttl_plan, np.zeros((lam_np.shape[0] - r,))]
-                    ),
-                    jnp.float32,
-                )
-                hit_lat = jnp.asarray(self.cache.hit_latency, jnp.float32)
-                cache_st = getattr(carry, "cache", None)
-                if cache_st is None or cache_st.shape != ttl_roll.shape:
-                    carry = carry._replace(
-                        cache=jnp.full(ttl_roll.shape, -jnp.inf)
+                    cache_spec = make_cache_spec(
+                        np.concatenate([hit, np.zeros((lam_np.shape[0] - r,))]),
+                        hit_latency=self.cache.hit_latency,
+                        hot_cost=self.cache.hot_cost(),
                     )
-            t0 = time.perf_counter()
-            if self.rollout_batched:
-                # every candidate rolled out + scored (the same composed
-                # empirical objective as the sequential loop, repair rows
-                # masked out) + cost-folded + argmin'd in ONE compiled
-                # device program; int(best) below is the replan's single
-                # host sync
-                scores, best_dev = batched_rollout_scores(
-                    carry,
-                    key,
-                    sols.pi,
-                    lam,
-                    jnp.asarray(d, jnp.float32),
-                    jnp.asarray(srv_rates, jnp.float32),
-                    jnp.asarray(avail),
-                    self.theta * sols.cost,  # device-side cost fold
-                    self.objective,
-                    n_clients=r,
-                    n_requests=self.rollout_requests,
-                    rollout_seeds=self.rollout_seeds,
-                    ttl=ttl_roll,
-                    hit_latency=0.0 if hit_lat is None else hit_lat,
-                    devices=self.rollout_devices,
-                )
-                # jaxcheck: JX001 ok the ONE host sync per replan (arbitration argmin)
-                best = int(best_dev)
-                self.last_scores = scores[: len(probs)]
+                lam = jnp.asarray(lam_np, jnp.float32)
+                objective = self._repair_objective() if with_repair else self.objective
+            with diag.span("replan.assemble"):
+                probs, starts = [], []
+                for t in thetas:
+                    for mk in masks:
+                        mask = np.broadcast_to(
+                            np.asarray(mk, bool), (r, avail.shape[-1])
+                        )
+                        if with_repair:
+                            mask = np.concatenate(
+                                [mask, np.asarray(repair.mask, bool)], axis=0
+                            )
+                        mask = jnp.asarray(mask)
+                        prob = JLCMProblem(
+                            lam=lam,
+                            k=jnp.asarray(k_vec),
+                            moments=mom,
+                            cost=jnp.asarray(self.cost, jnp.float32),
+                            theta=float(t),
+                            mask=mask,
+                            objective=objective,
+                            cache=cache_spec,
+                        )
+                        probs.append(prob)
+                        starts.append(feasible_uniform(mask, prob.k))
+                        if pi0 is not None:
+                            if with_repair:
+                                start, _ = augment_plan(pi0, lam_np[:r], repair)
+                            else:
+                                start = np.asarray(pi0)
+                            probs.append(prob)
+                            starts.append(jnp.asarray(start, jnp.float32))
+            with diag.span("replan.solve") as solve_span:
+                sols = solve_batch(probs, max_iters=self.max_iters, pi0=jnp.stack(starts))
+                with diag.span("replan.solve_wait"):
+                    jax.block_until_ready(sols.pi)
+            self.solve_walls.append(solve_span.seconds)
+            self.replans += 1
+
+            if carry is not None and key is not None:
+                with diag.span("replan.arbitrate") as arb_span:
+                    with diag.span("replan.rollout_fit"):
+                        d, srv_rates = self.estimator.fitted_shifted_exp()
+                    ttl_roll = hit_lat = None
+                    if self.cache is not None:
+                        # roll out with the planned TTLs so the scorer sees the
+                        # same thinned queue load the solver planned for (repair
+                        # rows TTL 0: never cached)
+                        ttl_roll = jnp.asarray(
+                            np.concatenate(
+                                [ttl_plan, np.zeros((lam_np.shape[0] - r,))]
+                            ),
+                            jnp.float32,
+                        )
+                        hit_lat = jnp.asarray(self.cache.hit_latency, jnp.float32)
+                        cache_st = getattr(carry, "cache", None)
+                        if cache_st is None or cache_st.shape != ttl_roll.shape:
+                            carry = carry._replace(
+                                cache=jnp.full(ttl_roll.shape, -jnp.inf)
+                            )
+                    if self.rollout_batched:
+                        # every candidate rolled out + scored (the same composed
+                        # empirical objective as the sequential loop, repair rows
+                        # masked out) + cost-folded + argmin'd in ONE compiled
+                        # device program; int(best) below is the replan's single
+                        # host sync
+                        scores, best_dev = batched_rollout_scores(
+                            carry,
+                            key,
+                            sols.pi,
+                            lam,
+                            jnp.asarray(d, jnp.float32),
+                            jnp.asarray(srv_rates, jnp.float32),
+                            jnp.asarray(avail),
+                            self.theta * sols.cost,  # device-side cost fold
+                            self.objective,
+                            n_clients=r,
+                            n_requests=self.rollout_requests,
+                            rollout_seeds=self.rollout_seeds,
+                            ttl=ttl_roll,
+                            hit_latency=0.0 if hit_lat is None else hit_lat,
+                            devices=self.rollout_devices,
+                        )
+                        with diag.span("replan.sync"):
+                            # jaxcheck: JX001 ok the ONE host sync per replan (arbitration argmin)
+                            best = int(best_dev)
+                        self.last_scores = scores[: len(probs)]
+                    else:
+                        from repro.storage.simulator import run_segment_raw
+
+                        cost_term = self.theta * np.asarray(sols.cost)
+                        scores = []
+                        for i in range(len(probs)):
+                            _, res = run_segment_raw(
+                                carry,
+                                key,
+                                sols.pi[i],
+                                lam,
+                                jnp.asarray(d, jnp.float32),
+                                jnp.asarray(srv_rates, jnp.float32),
+                                jnp.asarray(avail),
+                                self.rollout_requests,
+                                ttl_roll,
+                                0.0 if hit_lat is None else hit_lat,
+                            )
+                            lat_np = np.asarray(res.latency)
+                            fid_np = np.asarray(res.file_id)
+                            if with_repair:  # score client traffic only
+                                client = fid_np < r
+                                lat_np, fid_np = lat_np[client], fid_np[client]
+                            # same objective as the analytic fallback, with the
+                            # empirical composed objective (weighted mean + per-
+                            # class exceedance frequencies) replacing the loose,
+                            # backlog-blind analytic bound
+                            scores.append(
+                                empirical_objective(lat_np, fid_np, self.objective)
+                                + float(cost_term[i])
+                            )
+                        best = int(np.argmin(scores))
+                        self.last_scores = np.asarray(scores)
+                self.rollout_walls.append(arb_span.seconds)
             else:
-                from repro.storage.simulator import run_segment_raw
-
                 cost_term = self.theta * np.asarray(sols.cost)
-                scores = []
-                for i in range(len(probs)):
-                    _, res = run_segment_raw(
-                        carry,
-                        key,
-                        sols.pi[i],
-                        lam,
-                        jnp.asarray(d, jnp.float32),
-                        jnp.asarray(srv_rates, jnp.float32),
-                        jnp.asarray(avail),
-                        self.rollout_requests,
-                        ttl_roll,
-                        0.0 if hit_lat is None else hit_lat,
-                    )
-                    lat_np = np.asarray(res.latency)
-                    fid_np = np.asarray(res.file_id)
-                    if with_repair:  # score client traffic only
-                        client = fid_np < r
-                        lat_np, fid_np = lat_np[client], fid_np[client]
-                    # same objective as the analytic fallback, with the
-                    # empirical composed objective (weighted mean + per-
-                    # class exceedance frequencies) replacing the loose,
-                    # backlog-blind analytic bound
-                    scores.append(
-                        empirical_objective(lat_np, fid_np, self.objective)
-                        + float(cost_term[i])
-                    )
+                scores = (np.asarray(sols.latency_tight) + cost_term).tolist()
                 best = int(np.argmin(scores))
                 self.last_scores = np.asarray(scores)
-            self.rollout_walls.append(time.perf_counter() - t0)
-        else:
-            cost_term = self.theta * np.asarray(sols.cost)
-            scores = (np.asarray(sols.latency_tight) + cost_term).tolist()
-            best = int(np.argmin(scores))
-            self.last_scores = np.asarray(scores)
-        if sols.iterations is not None:
-            it = np.asarray(sols.iterations)
-            self.solve_iters.append(int(it[best] if it.ndim else it))
-        pi_best = np.asarray(sols.pi[best])
-        self.repair_pi = pi_best[r:] if with_repair else None
+            with diag.span("replan.deploy"):
+                if sols.iterations is not None:
+                    it = np.asarray(sols.iterations)
+                    # the vmapped while loop runs until its slowest lane stops
+                    diag.count("solver.trips", int(it.max()))
+                    diag.count("solver.lanes", int(it.size))
+                    self.solve_iters.append(int(it[best] if it.ndim else it))
+                pi_best = np.asarray(sols.pi[best])
+                self.repair_pi = pi_best[r:] if with_repair else None
         return pi_best[:r]
 
 
@@ -1021,68 +1030,68 @@ class HierarchicalReplanner:
         mom = self.estimator.moments()
         lam_c = self.cluster_rates(file_rates)
         cost = jnp.asarray(self.cost, jnp.float32)
-        t0 = time.perf_counter()
-        full = (
-            self.plan is None
-            or self._moments_moved(mom)
-            or self._solved_avail is None
-            or not np.array_equal(avail, self._solved_avail)
-        )
-        if full:
-            h = self.hierarchy._replace(lam=lam_c)
-            mask = jnp.asarray(
-                np.broadcast_to(avail, (h.n_clusters, avail.shape[-1]))
+        with diag.span("replan.solve") as solve_span:
+            full = (
+                self.plan is None
+                or self._moments_moved(mom)
+                or self._solved_avail is None
+                or not np.array_equal(avail, self._solved_avail)
             )
-            prob = build_problem(h, mom, cost, self.theta)._replace(
-                mask=mask
-            )
-            # warm AND cold candidates, arbitrated by solved objective
-            # (mirrors AdaptiveReplanner's candidate grid): a warm start
-            # from the incumbent can stall the relative stopping rule
-            # right at its starting point when the moments moved under
-            # it, while on mild drift it converges in a handful of
-            # iterations — solving both costs one extra batch lane and
-            # keeps whichever is actually better. The incumbent is only
-            # a valid candidate while every node it uses is up.
-            starts = [feasible_uniform(mask, prob.k)]
-            if self.plan is not None and bool(avail.all()):
-                starts.append(
-                    jnp.asarray(self.plan.cluster_pi, jnp.float32)
+            if full:
+                h = self.hierarchy._replace(lam=lam_c)
+                mask = jnp.asarray(
+                    np.broadcast_to(avail, (h.n_clusters, avail.shape[-1]))
                 )
-            sols = solve_batch(
-                [prob] * len(starts),
-                max_iters=self.max_iters,
-                eps=self.eps,
-                pi0=jnp.stack(starts),
-            )
-            # device argmin: transfer the winning index, not the whole
-            # objective vector (the same one-sync contract the rollout
-            # replanners' batched arbitration keeps)
-            best = int(jnp.argmin(sols.objective))
-            self.plan = FactoredPlan(
-                h, jnp.asarray(sols.pi[best]), lam_c.copy()
-            )
-            it = np.asarray(sols.iterations)
-            iters = int(it[best] if it.ndim else it)
-            self.resolved_counts.append(int(h.n_clusters))
-            self.full_solves += 1
-            self._solved_mom = mom
-            self._solved_avail = avail.copy()
-        else:
-            self.plan, info = resolve_incremental(
-                self.plan,
-                lam_c,
-                mom,
-                cost,
-                self.theta,
-                threshold=self.rate_threshold,
-                max_iters=self.max_iters,
-                eps=self.eps,
-            )
-            iters = int(info.iterations)
-            self.resolved_counts.append(int(info.n_resolved))
-        pi = np.asarray(jax.block_until_ready(materialize(self.plan)))
-        self.solve_walls.append(time.perf_counter() - t0)
+                prob = build_problem(h, mom, cost, self.theta)._replace(
+                    mask=mask
+                )
+                # warm AND cold candidates, arbitrated by solved objective
+                # (mirrors AdaptiveReplanner's candidate grid): a warm start
+                # from the incumbent can stall the relative stopping rule
+                # right at its starting point when the moments moved under
+                # it, while on mild drift it converges in a handful of
+                # iterations — solving both costs one extra batch lane and
+                # keeps whichever is actually better. The incumbent is only
+                # a valid candidate while every node it uses is up.
+                starts = [feasible_uniform(mask, prob.k)]
+                if self.plan is not None and bool(avail.all()):
+                    starts.append(
+                        jnp.asarray(self.plan.cluster_pi, jnp.float32)
+                    )
+                sols = solve_batch(
+                    [prob] * len(starts),
+                    max_iters=self.max_iters,
+                    eps=self.eps,
+                    pi0=jnp.stack(starts),
+                )
+                # device argmin: transfer the winning index, not the whole
+                # objective vector (the same one-sync contract the rollout
+                # replanners' batched arbitration keeps)
+                best = int(jnp.argmin(sols.objective))
+                self.plan = FactoredPlan(
+                    h, jnp.asarray(sols.pi[best]), lam_c.copy()
+                )
+                it = np.asarray(sols.iterations)
+                iters = int(it[best] if it.ndim else it)
+                self.resolved_counts.append(int(h.n_clusters))
+                self.full_solves += 1
+                self._solved_mom = mom
+                self._solved_avail = avail.copy()
+            else:
+                self.plan, info = resolve_incremental(
+                    self.plan,
+                    lam_c,
+                    mom,
+                    cost,
+                    self.theta,
+                    threshold=self.rate_threshold,
+                    max_iters=self.max_iters,
+                    eps=self.eps,
+                )
+                iters = int(info.iterations)
+                self.resolved_counts.append(int(info.n_resolved))
+            pi = np.asarray(jax.block_until_ready(materialize(self.plan)))
+        self.solve_walls.append(solve_span.seconds)
         self.solve_iters.append(iters)
         self.replans += 1
         return pi
@@ -1200,67 +1209,68 @@ class GeoAdaptiveReplanner:
                 if pi0 is not None:
                     probs.append(prob)
                     starts.append(jnp.asarray(np.asarray(pi0), jnp.float32))
-        t0 = time.perf_counter()
-        sols = solve_batch(probs, max_iters=self.max_iters, pi0=jnp.stack(starts))
-        jax.block_until_ready(sols.pi)
-        self.solve_walls.append(time.perf_counter() - t0)
+        with diag.span("replan.solve") as solve_span:
+            sols = solve_batch(probs, max_iters=self.max_iters, pi0=jnp.stack(starts))
+            with diag.span("replan.solve_wait"):
+                jax.block_until_ready(sols.pi)
+        self.solve_walls.append(solve_span.seconds)
         self.replans += 1
 
         if carry is not None and key is not None:
             d, srv_rates = self.estimator.fitted_shifted_exp()  # (C, m) each
             lam_cs_j = jnp.asarray(lam_cs, jnp.float32)
-            t0 = time.perf_counter()
-            if self.rollout_batched:
-                # geo twin of the fused arbitration: all candidates rolled
-                # out, scored under the composed empirical objective (NOT
-                # a bare latency mean — tenant weights/deadlines bind geo
-                # arbitration too), cost-folded, and argmin'd on device
-                scores, best_dev = batched_rollout_scores(
-                    carry,
-                    key,
-                    sols.pi,
-                    lam_cs_j,
-                    jnp.asarray(d, jnp.float32),
-                    jnp.asarray(srv_rates, jnp.float32),
-                    jnp.asarray(avail),
-                    self.theta * sols.cost,  # device-side cost fold
-                    self.objective,
-                    n_clients=r,
-                    n_requests=self.rollout_requests,
-                    rollout_seeds=self.rollout_seeds,
-                    devices=self.rollout_devices,
-                    geo=True,
-                )
-                # jaxcheck: JX001 ok the ONE host sync per replan (arbitration argmin)
-                best = int(best_dev)
-                self.last_scores = scores[: len(probs)]
-            else:
-                from repro.storage.simulator import run_geo_segment_raw
-
-                cost_term = self.theta * np.asarray(sols.cost)
-                scores = []
-                for i in range(len(probs)):
-                    _, res = run_geo_segment_raw(
+            with diag.span("replan.arbitrate") as arb_span:
+                if self.rollout_batched:
+                    # geo twin of the fused arbitration: all candidates rolled
+                    # out, scored under the composed empirical objective (NOT
+                    # a bare latency mean — tenant weights/deadlines bind geo
+                    # arbitration too), cost-folded, and argmin'd on device
+                    scores, best_dev = batched_rollout_scores(
                         carry,
                         key,
-                        sols.pi[i],
+                        sols.pi,
                         lam_cs_j,
                         jnp.asarray(d, jnp.float32),
                         jnp.asarray(srv_rates, jnp.float32),
                         jnp.asarray(avail),
-                        self.rollout_requests,
+                        self.theta * sols.cost,  # device-side cost fold
+                        self.objective,
+                        n_clients=r,
+                        n_requests=self.rollout_requests,
+                        rollout_seeds=self.rollout_seeds,
+                        devices=self.rollout_devices,
+                        geo=True,
                     )
-                    scores.append(
-                        empirical_objective(
-                            np.asarray(res.latency),
-                            np.asarray(res.file_id),
-                            self.objective,
+                    # jaxcheck: JX001 ok the ONE host sync per replan (arbitration argmin)
+                    best = int(best_dev)
+                    self.last_scores = scores[: len(probs)]
+                else:
+                    from repro.storage.simulator import run_geo_segment_raw
+
+                    cost_term = self.theta * np.asarray(sols.cost)
+                    scores = []
+                    for i in range(len(probs)):
+                        _, res = run_geo_segment_raw(
+                            carry,
+                            key,
+                            sols.pi[i],
+                            lam_cs_j,
+                            jnp.asarray(d, jnp.float32),
+                            jnp.asarray(srv_rates, jnp.float32),
+                            jnp.asarray(avail),
+                            self.rollout_requests,
                         )
-                        + float(cost_term[i])
-                    )
-                best = int(np.argmin(scores))
-                self.last_scores = np.asarray(scores)
-            self.rollout_walls.append(time.perf_counter() - t0)
+                        scores.append(
+                            empirical_objective(
+                                np.asarray(res.latency),
+                                np.asarray(res.file_id),
+                                self.objective,
+                            )
+                            + float(cost_term[i])
+                        )
+                    best = int(np.argmin(scores))
+                    self.last_scores = np.asarray(scores)
+            self.rollout_walls.append(arb_span.seconds)
         else:
             cost_term = self.theta * np.asarray(sols.cost)
             scores = (np.asarray(sols.latency_tight) + cost_term).tolist()

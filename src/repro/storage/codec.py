@@ -36,6 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import Array
 
+from repro import diag
+
 from . import rs
 
 # NOTE: repro.kernels imports are deferred into the functions below —
@@ -230,17 +232,26 @@ class CodecPlan:
             raise ValueError("file_ids, patterns, chunks must align")
         out: list[np.ndarray | None] = [None] * len(file_ids)
         by_group: dict[tuple[int, int], list[int]] = {}
-        for req, fid in enumerate(file_ids):
-            g = self.group_of(int(fid))
-            by_group.setdefault((g.n, g.k), []).append(req)
+        with diag.span("codec.group"):
+            for req, fid in enumerate(file_ids):
+                g = self.group_of(int(fid))
+                by_group.setdefault((g.n, g.k), []).append(req)
         for (n, k), reqs in by_group.items():
-            stacked = jnp.stack([jnp.asarray(chunks[i], jnp.uint8) for i in reqs])
-            decoded = decode_batch(
-                stacked, [patterns[i] for i in reqs], n, k, backend=backend
-            )
-            decoded = np.asarray(decoded)
-            for row, req in enumerate(reqs):
-                out[req] = decoded[row]
+            with diag.span("codec.group"):
+                stacked = jnp.stack(
+                    [jnp.asarray(chunks[i], jnp.uint8) for i in reqs]
+                )
+            with diag.span("codec.decode"):
+                decoded = decode_batch(
+                    stacked, [patterns[i] for i in reqs], n, k, backend=backend
+                )
+            with diag.span("codec.wait"):
+                decoded.block_until_ready()
+            with diag.span("codec.to_host", bytes=int(decoded.nbytes)):
+                decoded = np.asarray(decoded)
+            with diag.span("codec.scatter"):
+                for row, req in enumerate(reqs):
+                    out[req] = decoded[row]
         return out  # type: ignore[return-value]
 
 

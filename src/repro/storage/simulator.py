@@ -1156,21 +1156,24 @@ def _fleet_stream_batched(
         prep = lambda k, ca: _fleet_inputs(
             k, pi, lam_cs, overheads_cs, rates_cs, block, ttl_arr, cache=ca
         )
-        t, _, _, masks, service, hit, new_cache = jax.vmap(prep)(ckeys, cache)
-        latency, dep, busy = fcfs_scan(
-            t, masks, service, dep, busy, backend=backend
-        )
-        if cached:
-            latency = jnp.where(hit, jnp.asarray(hit_latency), latency)
-        inc = jnp.broadcast_to(
-            idx0 + jnp.arange(block) >= warm, latency.shape
-        )
-        wstats = stream_from_values(latency, sketch, include=inc)
-        stats = stream_merge(stats, wstats)
-        if cached:
-            hitcnt = hitcnt + jnp.sum(
-                jnp.logical_and(hit, inc), axis=1, dtype=jnp.int32
+        with diag.scope("fleet.inputs"):
+            t, _, _, masks, service, hit, new_cache = jax.vmap(prep)(ckeys, cache)
+        with diag.scope("fleet.fcfs"):
+            latency, dep, busy = fcfs_scan(
+                t, masks, service, dep, busy, backend=backend
             )
+            if cached:
+                latency = jnp.where(hit, jnp.asarray(hit_latency), latency)
+        with diag.scope("fleet.stats"):
+            inc = jnp.broadcast_to(
+                idx0 + jnp.arange(block) >= warm, latency.shape
+            )
+            wstats = stream_from_values(latency, sketch, include=inc)
+            stats = stream_merge(stats, wstats)
+            if cached:
+                hitcnt = hitcnt + jnp.sum(
+                    jnp.logical_and(hit, inc), axis=1, dtype=jnp.int32
+                )
         new_carry = (
             dep, busy, t[:, -1], new_cache, stats, hitcnt, idx0 + block
         )

@@ -197,3 +197,134 @@ class TestClosedLoopContract:
         stats = diag.hot_path_registry()["serving.batched_rollout_scores"]
         assert stats.guarded_calls >= 3
         assert stats.recompiles == 0
+
+
+REPLAN_SPANS = (
+    "replan.step", "replan.estimate", "replan.assemble", "replan.solve",
+    "solve.stack", "solve.dispatch", "replan.solve_wait", "replan.arbitrate",
+    "replan.rollout_fit", "serving.batched_rollout_scores", "replan.sync",
+    "replan.deploy", "solver.trips", "solver.lanes", "loop.simulate",
+    "loop.observe",
+)
+
+
+def _host_events(trace_dir):
+    """(name, start_ns, end_ns, stats) of every /host:CPU event; stats
+    are read for the program's counters only."""
+    from jax.profiler import ProfileData
+
+    path = sorted(trace_dir.glob("**/*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                stats = dict(e.stats) if e.name.startswith("solver.") else {}
+                out.append((e.name, e.start_ns, e.start_ns + e.duration_ns, stats))
+    return out
+
+
+def _inside(inner, outers):
+    return any(s <= inner[1] and inner[2] <= e for _, s, e, _ in outers)
+
+
+class TestSpansAndCounters:
+    @pytest.fixture(scope="class")
+    def traced_loop(self, tmp_path_factory):
+        """A two-segment steady-state closed loop under a profiler trace,
+        with each replan's batched solve captured."""
+        import dataclasses
+
+        from repro.scenarios import get_scenario, run_scenario
+        from repro.serving import router
+
+        spec = dataclasses.replace(
+            get_scenario("steady-state"), n_segments=2, requests_per_segment=200
+        )
+        orig = router.solve_batch
+        sols = []
+
+        def capture(probs, **kw):
+            out = orig(probs, **kw)
+            sols.append(out)
+            return out
+
+        router.solve_batch = capture
+        trace_dir = tmp_path_factory.mktemp("trace")
+        try:
+            with jax.profiler.trace(str(trace_dir)):
+                out = run_scenario(spec, "adaptive", seed=3)
+        finally:
+            router.solve_batch = orig
+        return out, sols, _host_events(trace_dir)
+
+    def test_every_replan_and_loop_span_appears(self, traced_loop):
+        _, _, events = traced_loop
+        names = {e[0] for e in events}
+        assert set(REPLAN_SPANS) <= names
+
+    def test_solve_wait_nests_in_solve_in_step(self, traced_loop):
+        _, _, events = traced_loop
+        by = lambda n: [e for e in events if e[0] == n]  # noqa: E731
+        waits, solves, steps = by("replan.solve_wait"), by("replan.solve"), by(
+            "replan.step")
+        assert waits
+        for w in waits:
+            assert _inside(w, solves)
+        for s in solves:
+            assert _inside(s, steps)
+
+    def test_trips_counter_is_the_slowest_lane(self, traced_loop):
+        _, sols, events = traced_loop
+        trips = [e[3]["value"] for e in events if e[0] == "solver.trips"]
+        lanes = [e[3]["value"] for e in events if e[0] == "solver.lanes"]
+        assert trips == [int(np.max(np.asarray(s.iterations))) for s in sols]
+        assert lanes == [int(np.asarray(s.iterations).size) for s in sols]
+
+    def test_span_counts_match_the_replanner_walls(self, traced_loop):
+        out, _, events = traced_loop
+        names = [e[0] for e in events]
+        assert names.count("replan.solve") == len(out.solve_walls) >= 1
+        assert names.count("replan.arbitrate") == len(out.rollout_walls) >= 1
+        assert names.count("loop.simulate") == names.count("loop.observe") == 2
+
+    def test_walls_are_the_spans_seconds(self, traced_loop):
+        out, _, events = traced_loop
+        solve = [e[2] - e[1] for e in events if e[0] == "replan.solve"]
+        for wall, ns in zip(out.solve_walls, solve):
+            assert wall * 1e9 <= ns + 1e3  # perf_counter runs inside the span
+
+    def test_no_trace_leaves_nothing_behind(self, tmp_path):
+        before = set(diag.hot_path_registry())
+        with diag.span("t.untraced", bytes=3) as sp:
+            diag.count("t.untraced_count", 1)
+        assert sp.seconds >= 0.0
+        assert set(diag.hot_path_registry()) == before
+        with jax.profiler.trace(str(tmp_path)):
+            with diag.span("t.traced"):
+                pass
+        names = {e[0] for e in _host_events(tmp_path)}
+        assert "t.traced" in names
+        assert not {"t.untraced", "t.untraced_count"} & names
+
+    def test_hot_path_opens_a_span(self, tmp_path, disarmed):
+        with jax.profiler.trace(str(tmp_path)):
+            with diag.hot_path("t.spanned"):
+                pass
+        assert "t.spanned" in {e[0] for e in _host_events(tmp_path)}
+
+    def test_scope_names_ops_and_marks_the_program(self):
+        @jax.jit
+        def f(x):
+            with diag.scope("t.scoped"):
+                y = jnp.sin(x) * 2.0
+            return y + 1.0
+
+        lowered = f.lower(jnp.ones(3))
+        # in the program itself (what the compilation cache keys on)...
+        assert 'repro_scope = "t.scoped"' in lowered.as_text()
+        # ...and in each operation's scope path
+        hlo = lowered.compile().as_text()
+        assert "/t.scoped/" in hlo
+        np.testing.assert_array_equal(f(jnp.ones(3)), jnp.sin(jnp.ones(3)) * 2.0 + 1.0)
