@@ -73,6 +73,7 @@ from .streaming import (
     DEFAULT_SKETCH,
     SketchSpec,
     StreamingStats,
+    bucketize,
     stream_from_values,
     stream_init,
     stream_mean,
@@ -649,11 +650,13 @@ def generate_geo_workload(
     categorical (site, file) marks. Returns ``(t, file_id, site_id)``,
     each (N,).
 
-    The marks are drawn by inverse-CDF search (one uniform + a
-    ``searchsorted`` into the C*r-bin CDF per request) instead of
-    Gumbel-max ``jax.random.categorical``: identical distribution at
-    ~1/10th the elementwise work, which matters on the fleet path where
-    workload generation would otherwise dominate the whole simulation
+    The marks are drawn by inverse CDF: one uniform per request, whose
+    bin in the C*r-bin CDF is the number of CDF entries ``<= u``
+    (:func:`~.streaming.bucketize`: counted on the TPU, binary-searched
+    elsewhere, the same index either way). That is Gumbel-max
+    ``jax.random.categorical``'s distribution at a fraction of its
+    elementwise work, which matters on the fleet path where workload
+    generation would otherwise dominate the whole simulation
     (`benchmarks/fleet_scale.py`).
     """
     lam_cs = jnp.asarray(lam_cs)
@@ -664,9 +667,7 @@ def generate_geo_workload(
     t = jnp.cumsum(gaps)
     cdf = jnp.cumsum(flat / jnp.sum(flat))
     u = jax.random.uniform(k_mark, (n_requests,))
-    marks = jnp.clip(
-        jnp.searchsorted(cdf, u, side="right"), 0, flat.shape[0] - 1
-    )
+    marks = jnp.clip(bucketize(cdf, u), 0, flat.shape[0] - 1)
     return t, marks % r, marks // r
 
 

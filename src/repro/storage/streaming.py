@@ -53,6 +53,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import Array
 
+from repro.kernels.fcfs_queue import _on_tpu
+
 
 @dataclasses.dataclass(frozen=True)
 class SketchSpec:
@@ -160,11 +162,12 @@ def stream_fold(
     mean_b = jnp.sum(x * incf, axis=-1) / n_bf
     dev = jnp.where(inc, x - mean_b[..., None], 0.0)
     m2_b = jnp.sum(dev * dev, axis=-1)
-    min_b = jnp.min(jnp.where(inc, x, jnp.inf), axis=-1)
-    max_b = jnp.max(jnp.where(inc, x, -jnp.inf), axis=-1)
+    # the initial values make an empty block (K == 0) an identity fold
+    min_b = jnp.min(jnp.where(inc, x, jnp.inf), axis=-1, initial=jnp.inf)
+    max_b = jnp.max(jnp.where(inc, x, -jnp.inf), axis=-1, initial=-jnp.inf)
 
     edges = jnp.asarray(spec.edges, jnp.float32)
-    idx = jnp.searchsorted(edges, x, side="right")  # (..., K) in [0, bins+1]
+    idx = bucketize(edges, x)  # (..., K) in [0, bins+1]
     # masked-out values are routed to bucket 0 with weight 0
     hist_b = _scatter_counts(
         jnp.where(inc, idx, 0), inc.astype(jnp.int32), spec.n_buckets
@@ -176,11 +179,28 @@ def stream_fold(
     return stream_merge(stats, block)
 
 
+def bucketize(edges: Array, x: Array, *, method: str = "auto") -> Array:
+    """Bucket of each ``x`` among the non-decreasing ``edges``: the
+    number of edges ``<= x``, i.e. ``jnp.searchsorted(edges, x,
+    side="right")``, whichever ``method`` computes it.
+
+    ``"auto"`` counts on the TPU (``"compare_all"``: a compare and a sum
+    that XLA fuses into one reduction over the table, with no gather) and
+    binary-searches elsewhere (``"scan"``). Each of the scan's
+    log2(len(edges)) levels gathers one table entry per query, which a
+    TPU does slowly; on a CPU the scan is the cheaper of the two.
+    """
+    if method == "auto":
+        method = "compare_all" if _on_tpu() else "scan"
+    return jnp.searchsorted(edges, x, side="right", method=method)
+
+
 def _scatter_counts(idx: Array, weights: Array, n_buckets: int) -> Array:
     """Histogram of ``idx`` (..., K) with integer ``weights`` into
     (..., n_buckets); batched scatter-add."""
-    flat_idx = idx.reshape(-1, idx.shape[-1])
-    flat_w = weights.reshape(-1, weights.shape[-1])
+    rows_shape = (int(np.prod(idx.shape[:-1])), idx.shape[-1])  # K may be 0
+    flat_idx = idx.reshape(rows_shape)
+    flat_w = weights.reshape(rows_shape)
     out = jnp.zeros((flat_idx.shape[0], n_buckets), jnp.int32)
     rows = jnp.broadcast_to(
         jnp.arange(flat_idx.shape[0])[:, None], flat_idx.shape

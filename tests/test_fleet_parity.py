@@ -187,6 +187,30 @@ class TestBatchingParity:
         a, b = float(one.mean_latency()), float(chunked.mean_latency())
         assert abs(a - b) / b < 0.15, (a, b)
 
+    def test_search_methods_give_identical_fleets(self, fabric, monkeypatch):
+        """The TPU's compare-and-count and the CPU's binary search find the
+        same marks and sketch buckets, so a chunked streaming fleet's
+        statistics and windows are bit-identical under either."""
+        from repro.storage import streaming
+
+        pi = feasible_uniform(jnp.ones((4, fabric.m), bool), K)
+        lam_cs = jnp.asarray(
+            np.asarray(fabric.uniform_mix(4)).T * 0.1, jnp.float32
+        )
+        runs = []
+        for on_tpu in (False, True):
+            monkeypatch.setattr(streaming, "_on_tpu", lambda: on_tpu)
+            jax.clear_caches()  # the method is fixed when a program traces
+            out = simulate_fleet(
+                jax.random.key(16), pi, lam_cs, fabric, 12.5, 300, 3,
+                devices="never", stream=True, n_chunks=4,
+            )
+            runs.append(jax.tree.map(np.asarray, (out.stream, out.windows)))
+        jax.clear_caches()
+        for a, b in zip(*(jax.tree.leaves(r) for r in runs)):
+            np.testing.assert_array_equal(a, b)
+        assert runs[0][1].count.shape == (3, 4)
+
     def test_streaming_path_materializes_nothing(self, fabric):
         pi = feasible_uniform(jnp.ones((4, fabric.m), bool), K)
         lam_cs = jnp.asarray(

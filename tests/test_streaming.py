@@ -19,10 +19,12 @@ import pytest
 
 from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
 
+from repro.storage import streaming
 from repro.storage.streaming import (
     DEFAULT_SKETCH,
     SketchSpec,
     StreamingStats,
+    bucketize,
     stream_fold,
     stream_from_values,
     stream_init,
@@ -177,6 +179,99 @@ class TestSketch:
         assert spec.rel_error == pytest.approx(spec.growth - 1.0)
 
 
+def _tahoe_cdf():
+    """The 1000-bin mark CDF of the paper's Tahoe catalog (r = 1000 files,
+    rates by thirds), built as `generate_geo_workload` builds it."""
+    lam = np.zeros(1000, np.float32)
+    for i, rate in enumerate((1.25e-4, 1.25e-4, 1.0 / 9600)):
+        lam[i::3] = rate
+    flat = jnp.asarray(lam)
+    return jnp.cumsum(flat / jnp.sum(flat))
+
+
+def _around(edges):
+    """Queries that hit a table hard: every entry, its float32 neighbours
+    on both sides, values past both ends, 0 and +-inf."""
+    e = np.asarray(edges, np.float32)
+    return np.concatenate([
+        e,
+        np.nextafter(e, np.float32(np.inf)),
+        np.nextafter(e, np.float32(-np.inf)),
+        np.asarray([e[0] / 2, e[0] - 1, e[-1] * 2, e[-1] + 1, 0.0,
+                    np.inf, -np.inf], np.float32),
+    ])
+
+
+def _log_uniform(lo, hi, n, seed):
+    rng = np.random.default_rng(seed)
+    return np.exp(rng.uniform(np.log(lo), np.log(hi), n)).astype(np.float32)
+
+
+def _tables():
+    tied = np.asarray([0.5, 1.0, 1.0, 1.0, 2.0, 3.0, 3.0], np.float32)
+    cdf = np.asarray(_tahoe_cdf())
+    u = np.asarray(jax.random.uniform(jax.random.key(0), (1 << 14,)))
+
+    def sketch(spec, seed):
+        x = _log_uniform(spec.lo / 10, spec.hi * 10, 4096, seed)
+        return spec.edges.astype(np.float32), np.concatenate(
+            [_around(spec.edges), x]
+        )
+
+    return {
+        "ties": (
+            tied, np.concatenate([_around(tied), _log_uniform(0.1, 10, 256, 0)])
+        ),
+        "tahoe_cdf": (cdf, np.concatenate([_around(cdf), u])),
+        "default_sketch": sketch(DEFAULT_SKETCH, 2),
+        "spec_256": sketch(SPEC, 3),
+    }
+
+
+class TestBucketize:
+    """`bucketize` is ``searchsorted(side="right")`` whichever way it is
+    computed: the fleet's marks and sketch buckets must not move when the
+    TPU counts where the CPU binary-searches. (`DEFAULT_SKETCH` is the
+    fleet benchmark cell's 512-bin sketch.)"""
+
+    @pytest.mark.parametrize("method", ["scan", "compare_all"])
+    @pytest.mark.parametrize(
+        "table", ["ties", "tahoe_cdf", "default_sketch", "spec_256"]
+    )
+    def test_matches_numpy(self, method, table):
+        edges, x = _tables()[table]
+        got = bucketize(jnp.asarray(edges), jnp.asarray(x), method=method)
+        want = np.searchsorted(edges, x, side="right")
+        np.testing.assert_array_equal(np.asarray(got), want)
+        # the same queries as a (2, K) block, the fleet chunk step's shape
+        k = x.size // 2
+        block = jnp.asarray(x[: 2 * k]).reshape(2, k)
+        got2 = bucketize(jnp.asarray(edges), block, method=method)
+        np.testing.assert_array_equal(np.asarray(got2).ravel(), want[: 2 * k])
+
+    @pytest.mark.parametrize("on_tpu", [False, True])
+    def test_stream_fold_buckets_and_mask(self, monkeypatch, on_tpu):
+        """`stream_fold` takes each platform's method (compare-and-count
+        on the TPU) and counts only the included values, +-inf in the
+        clamp buckets."""
+        monkeypatch.setattr(streaming, "_on_tpu", lambda: on_tpu)
+        x = np.concatenate([
+            _log_uniform(1e-4, 1e5, 1000, 4), _around(DEFAULT_SKETCH.edges)
+        ])
+        inc = np.random.default_rng(5).random(x.size) < 0.7
+        inc[-2:] = True  # +-inf
+        s = stream_from_values(
+            jnp.asarray(x), DEFAULT_SKETCH, include=jnp.asarray(inc)
+        )
+        edges = DEFAULT_SKETCH.edges.astype(np.float32)
+        want = np.bincount(
+            np.searchsorted(edges, x[inc], side="right"),
+            minlength=DEFAULT_SKETCH.n_buckets,
+        )
+        np.testing.assert_array_equal(np.asarray(s.hist), want)
+        assert want[0] > 0 and want[-1] > 0
+
+
 class TestSimResultStream:
     def test_simulate_exposes_stream(self):
         """`simulate(..., sketch=...)` folds post-warmup latencies into a
@@ -210,10 +305,11 @@ class TestSimResultStream:
         assert res.stream is None
 
 
+# hypothesis refuses float32 bounds that float32 cannot hold exactly
 pos_floats = st.lists(
     st.floats(
-        min_value=2e-3, max_value=5e2, allow_nan=False, allow_infinity=False,
-        width=32,
+        min_value=float(np.float32(2e-3)), max_value=float(np.float32(5e2)),
+        allow_nan=False, allow_infinity=False, width=32,
     ),
     min_size=4,
     max_size=400,
