@@ -64,7 +64,12 @@ from .objectives import (
     make_objective,
     refresh_shared_z,
 )
-from .projection import feasible_uniform, project_capped_simplex
+from .projection import (
+    feasible_uniform,
+    project_capped_simplex,
+    rack_count,
+    round_racks,
+)
 from .queueing import (
     ServiceMoments,
     exponential_moments,

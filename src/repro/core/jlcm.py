@@ -67,7 +67,12 @@ from .objectives import (
     composed_latency,
     refresh_shared_z,
 )
-from .projection import feasible_uniform, project_capped_simplex
+from .projection import (
+    feasible_uniform,
+    project_capped_simplex,
+    rack_count,
+    round_racks,
+)
 from .queueing import (
     ServiceMoments,
     node_arrival_rates,
@@ -77,6 +82,10 @@ from .queueing import (
 
 SUPPORT_TOL = 1e-3  # pi below this counts as "not placed" when reading S_i
 BACKTRACK_SLACK = 1e-9  # accept a step iff obj <= prev + this
+# the rack-domain re-solve stops only once the Frank-Wolfe gap on its
+# placement is under this share of the latency bound (as well as on the
+# relative tolerance)
+RACK_GAP_TOL = 5e-3
 
 
 class JLCMProblem(NamedTuple):
@@ -113,6 +122,13 @@ class JLCMProblem(NamedTuple):
     # see the congestion the frozen traffic causes. None = no frozen
     # traffic, bit-for-bit the standalone solve
     background: Array | None = None
+    # failure domains: (m,) rack index of each node, racks equal in size
+    # and laid out rack-major (projection.rack_count). A read then takes
+    # at most one chunk from a rack (the rack caps of the projection) and
+    # the deployed placement stores at most one chunk of a row per rack
+    # (the per-rack round of _solve_racks). None = no domains, bit-for-bit
+    # the capped-simplex solve
+    domain: Array | None = None
 
     @property
     def r(self) -> int:
@@ -139,6 +155,9 @@ class JLCMSolution(NamedTuple):
     # solver iterations actually run (scalar for `solve`, (B,) for
     # `solve_batch`); what the warm-start win is measured by
     iterations: Array | None = None
+    # rack-domain problems only: the (row, rack) pairs the per-rack round
+    # collapsed from more than one host above SUPPORT_TOL to one
+    rack_merges: Array | None = None
 
 
 def _true_cost(
@@ -241,6 +260,8 @@ def _device_merged_loop(
     lr: Array,
     eps: Array,
     max_iters: int,
+    racks: int | None = None,
+    gap_racks: int | None = None,
 ) -> tuple[Array, Array, Array, Array]:
     """Merged-timescale JLCM entirely on device.
 
@@ -250,11 +271,15 @@ def _device_merged_loop(
     re-growth on acceptance / a 16x shrink on persistent failure (the
     round probed down to lr/16 already). Stops on the
     paper's relative tolerance or when lr collapses, with `max_iters` as
-    the trip-count bound of the ``lax.while_loop``.
+    the trip-count bound of the ``lax.while_loop``. With ``racks`` every
+    projection carries the rack caps. With ``gap_racks`` (``mask`` holding
+    at most one host of a row per rack) the relative tolerance stops the
+    loop only where the Frank-Wolfe gap on ``mask`` is under
+    ``RACK_GAP_TOL`` of the latency bound (:func:`_placement_gap`).
 
     Returns (pi, z, trace, iters); trace is NaN beyond entry `iters`.
     """
-    pi = project_capped_simplex(pi, prob.k, mask)
+    pi = project_capped_simplex(pi, prob.k, mask, racks=racks)
     z = _refresh_z(pi, prob)
     prev = smoothed_objective(pi, z, prob, beta)
 
@@ -281,7 +306,10 @@ def _device_merged_loop(
             g = _merged_grad(s.pi, s.z, prob, beta)
 
             def attempt(step_lr):
-                p = project_capped_simplex(s.pi - step_lr * g, prob.k, mask)
+                with diag.scope("jlcm.project"):
+                    p = project_capped_simplex(
+                        s.pi - step_lr * g, prob.k, mask, racks=racks
+                    )
                 zz = _refresh_z(p, prob)
                 return p, zz, smoothed_objective(p, zz, prob, beta)
 
@@ -316,6 +344,9 @@ def _device_merged_loop(
                 accepted,
                 jnp.abs(s.prev - obj) < eps * jnp.maximum(1.0, jnp.abs(obj)),
             )
+            if gap_racks is not None:
+                gap = _placement_gap(s.pi, g, mask, prob.k, gap_racks)
+                converged &= gap <= RACK_GAP_TOL * _latency_term(s.pi, s.z, prob)
             return _LoopState(
                 pi=pi_n,
                 z=z_n,
@@ -328,6 +359,17 @@ def _device_merged_loop(
 
     out = jax.lax.while_loop(cond, body, state)
     return out.pi, out.z, out.trace, out.t
+
+
+def _placement_gap(pi: Array, g: Array, mask: Array, k: Array, racks: int) -> Array:
+    """Frank-Wolfe gap of ``pi`` for gradient ``g`` over the plans on
+    ``mask``, which holds at most one host of a row per rack: <g, pi> less
+    each row's k smallest entries of ``g`` on ``mask`` (the vertex of its
+    capped simplex), found among the row's one host per rack."""
+    shape = pi.shape[:-1] + (racks, pi.shape[-1] // racks)
+    best = jnp.sort(jnp.min(jnp.where(mask, g, jnp.inf).reshape(shape), axis=-1), axis=-1)
+    take = (jnp.arange(racks) < k[..., None]) & jnp.isfinite(best)
+    return jnp.sum(g * pi) - jnp.sum(jnp.where(take, best, 0.0))
 
 
 def _finalize(pi: Array, z: Array, prob: JLCMProblem, trace: Array) -> JLCMSolution:
@@ -387,8 +429,35 @@ def _finalize(pi: Array, z: Array, prob: JLCMProblem, trace: Array) -> JLCMSolut
     )
 
 
-@functools.partial(jax.jit, static_argnames=("max_iters",))
-def _solve_merged_device(pi0, prob, mask, beta, lr, eps, max_iters):
+def _solve_racks(pi0, prob, mask, beta, lr, eps, max_iters, racks):
+    """The rack-domain solve: the rack-capped loop for up to half of
+    ``max_iters``, the round to one host per (row, rack)
+    (:func:`round_racks`), then the loop again on the rounded placement
+    for the trips left: the re-solve on the merged placement. There a row
+    holds at most one host of a rack, so the box x <= 1 is the rack cap and
+    the re-solve is the plain capped simplex on that placement; it stops
+    once its plan is near stationary there (``RACK_GAP_TOL``).
+
+    Returns (solution, iterations), the trace holding both loops' trips."""
+    half = max_iters // 2
+    pi, _, trace1, t1 = _device_merged_loop(
+        pi0, prob, mask, beta, lr, eps, half, racks
+    )
+    with diag.scope("jlcm.round"):
+        pi, keep, merges = round_racks(pi, mask, racks, SUPPORT_TOL, prob.lam)
+    pi, z, trace2, t2 = _device_merged_loop(
+        pi, prob, keep, beta, lr, eps, max_iters - half, gap_racks=racks
+    )
+    trace = jnp.full((max_iters + 1,), jnp.nan, trace1.dtype).at[: half + 1].set(trace1)
+    trace = jax.lax.dynamic_update_slice(trace, trace2, (t1,))
+    with diag.scope("jlcm.finalize"):
+        sol = _finalize(pi, z, prob, trace)
+    return sol._replace(rack_merges=merges), t1 + t2
+
+
+def _solve_one(pi0, prob, mask, beta, lr, eps, max_iters, racks):
+    if racks is not None:
+        return _solve_racks(pi0, prob, mask, beta, lr, eps, max_iters, racks)
     pi, z, trace, iters = _device_merged_loop(
         pi0, prob, mask, beta, lr, eps, max_iters
     )
@@ -396,14 +465,15 @@ def _solve_merged_device(pi0, prob, mask, beta, lr, eps, max_iters):
         return _finalize(pi, z, prob, trace), iters
 
 
-@functools.partial(jax.jit, static_argnames=("max_iters",))
-def _solve_merged_device_batch(pi0, prob, mask, beta, lr, eps, max_iters):
+@functools.partial(jax.jit, static_argnames=("max_iters", "racks"))
+def _solve_merged_device(pi0, prob, mask, beta, lr, eps, max_iters, racks=None):
+    return _solve_one(pi0, prob, mask, beta, lr, eps, max_iters, racks)
+
+
+@functools.partial(jax.jit, static_argnames=("max_iters", "racks"))
+def _solve_merged_device_batch(pi0, prob, mask, beta, lr, eps, max_iters, racks=None):
     def one(p0, pr, mk):
-        pi, z, trace, iters = _device_merged_loop(
-            p0, pr, mk, beta, lr, eps, max_iters
-        )
-        with diag.scope("jlcm.finalize"):
-            return _finalize(pi, z, pr, trace), iters
+        return _solve_one(p0, pr, mk, beta, lr, eps, max_iters, racks)
 
     return jax.vmap(one)(pi0, prob, mask)
 
@@ -557,8 +627,11 @@ def solve(
             "add it to (solve the geo problem densely instead)"
         )
     mask = _resolve_mask(prob)
+    racks = rack_count(prob.domain, prob.m)
+    if racks is not None and mode != "merged":
+        raise ValueError(f"rack domains are solved in merged mode only, not {mode!r}")
     if pi0 is None:
-        pi = feasible_uniform(mask, prob.k)
+        pi = feasible_uniform(mask, prob.k, racks)
     else:
         pi = jnp.asarray(pi0)
         if pi.shape != mask.shape:
@@ -566,7 +639,7 @@ def solve(
                 f"pi0 shape {pi.shape} does not match the problem's "
                 f"(r, m) = {tuple(mask.shape)}"
             )
-    pi = project_capped_simplex(pi, prob.k, mask)
+    pi = project_capped_simplex(pi, prob.k, mask, racks=racks)
 
     if mode == "merged":
         with diag.hot_path(
@@ -574,13 +647,17 @@ def solve(
         ):
             sol, iters = _solve_merged_device(
                 pi,
-                prob._replace(mask=None),
+                prob._replace(mask=None, domain=None),
                 mask,
                 jnp.asarray(beta, jnp.float32),
                 jnp.asarray(lr, jnp.float32),
                 jnp.asarray(eps, jnp.float32),
                 max_iters,
+                racks,
             )
+        if racks is not None:
+            # jaxcheck: JX001 ok end-of-solve counter, one read beside the trace trim
+            diag.count("plan.rack_merges", int(sol.rack_merges))
         # single host sync at the end: trim the NaN-padded trace
         return sol._replace(
             # jaxcheck: JX001 ok deliberate end-of-solve trace trim, one sync
@@ -673,7 +750,7 @@ def stack_problems(probs: Sequence[JLCMProblem]) -> JLCMProblem:
                     "hit vector length; values may vary, e.g. a capacity "
                     "sweep)"
                 )
-    for field in ("cost_weight", "background"):
+    for field in ("cost_weight", "background", "domain"):
         vals = [getattr(p, field) for p in probs]
         if any(v is None for v in vals) and not all(v is None for v in vals):
             raise ValueError(
@@ -692,10 +769,15 @@ def stack_problems(probs: Sequence[JLCMProblem]) -> JLCMProblem:
         p._replace(
             theta=jnp.asarray(p.theta, jnp.float32),
             mask=_resolve_mask(p),
+            domain=None,
         )
         for p in probs
     ]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *normalized)
+    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *normalized)
+    if probs[0].domain is None:
+        return stacked
+    # the rack layout sets the compiled program's shapes: kept on the host
+    return stacked._replace(domain=np.stack([np.asarray(p.domain) for p in probs]))
 
 
 def solve_batch(
@@ -725,8 +807,9 @@ def solve_batch(
         if stacked.mask is None:
             raise ValueError("stacked problems must carry an explicit mask")
         mask = jnp.asarray(stacked.mask, bool)
+        racks = rack_count(stacked.domain, stacked.m)
         if pi0 is None:
-            pi0 = feasible_uniform(mask, stacked.k)
+            pi0 = feasible_uniform(mask, stacked.k, racks)
         else:
             pi0 = jnp.asarray(pi0)
             if pi0.shape not in (mask.shape, mask.shape[1:]):
@@ -739,12 +822,13 @@ def solve_batch(
     with diag.span("solve.dispatch"):
         sol, iters = _solve_merged_device_batch(
             pi0,
-            stacked._replace(mask=None),
+            stacked._replace(mask=None, domain=None),
             mask,
             jnp.asarray(beta, jnp.float32),
             jnp.asarray(lr, jnp.float32),
             jnp.asarray(eps, jnp.float32),
             max_iters,
+            racks,
         )
     return sol._replace(iterations=iters)
 
@@ -754,14 +838,17 @@ def solve_batch(
 # ---------------------------------------------------------------------------
 
 
-def proportional_lb_pi(mask: Array, k: Array, moments: ServiceMoments) -> Array:
+def proportional_lb_pi(
+    mask: Array, k: Array, moments: ServiceMoments, racks: int | None = None
+) -> Array:
     """'Oblivious LB': dispatch proportional to service rates on a given
-    placement (then projected to the feasible polytope)."""
+    placement (then projected to the feasible polytope, rack caps
+    included with ``racks``)."""
     mask = jnp.asarray(mask, bool)
     mu = jnp.broadcast_to(moments.mu, mask.shape)
     w = jnp.where(mask, mu, 0.0)
     pi = jnp.asarray(k)[:, None] * w / jnp.sum(w, axis=-1, keepdims=True)
-    return project_capped_simplex(pi, k, mask)
+    return project_capped_simplex(pi, k, mask, racks=racks)
 
 
 def random_placement_mask(key: Array, r: int, m: int, n: Array) -> Array:

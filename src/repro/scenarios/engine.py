@@ -86,6 +86,7 @@ from repro.core import (
     JLCMProblem,
     materialize,
     proportional_lb_pi,
+    rack_count,
     solve,
     solve_hierarchical,
 )
@@ -279,6 +280,7 @@ def initial_plan(
         theta=spec.theta,
         objective=spec.objective(),
         cache=cache,
+        domain=cluster.domain,
     )
     sol = solve(prob, max_iters=max_iters)
     return np.asarray(sol.pi), mom, sol
@@ -288,7 +290,8 @@ def oblivious_plan(spec: ScenarioSpec, cluster: Cluster) -> np.ndarray:
     """Fig.-9 'Oblivious LB': mu-proportional dispatch on full support."""
     mom = cluster.moments(spec.chunk_mb)
     mask = jnp.ones((spec.r, cluster.m), bool)
-    return np.asarray(proportional_lb_pi(mask, jnp.asarray(spec.k), mom))
+    racks = rack_count(cluster.domain, cluster.m)
+    return np.asarray(proportional_lb_pi(mask, jnp.asarray(spec.k), mom, racks))
 
 
 def run_scenario(
@@ -509,6 +512,7 @@ def run_scenario(
                 estimator=moment_est,
                 objective=spec.objective(),
                 cache=cache_model if cache_aware else None,
+                domain=cluster.domain,
             )
         if has_cache and cache_aware:
             # seed the inversion state with what is actually deployed

@@ -57,6 +57,7 @@ from repro.core import (
     madow_sample,
     materialize,
     project_capped_simplex,
+    rack_count,
     resolve_incremental,
     solve,
     solve_batch,
@@ -698,6 +699,10 @@ class AdaptiveReplanner:
     # windows) and far below the cache-blind plan's permanent
     # over-provisioning.
     surge_margin: float = 1.25
+    # (m,) rack of each node (``Cluster.domain``): every candidate solve
+    # carries the rack caps and deploys one chunk per rack. None = no
+    # failure domains
+    domain: np.ndarray | None = None
 
     def _repair_objective(self) -> ObjectiveSpec | None:
         """The client objective extended with a zero-weight repair class.
@@ -816,6 +821,7 @@ class AdaptiveReplanner:
                 objective = self._repair_objective() if with_repair else self.objective
             with diag.span("replan.assemble"):
                 probs, starts = [], []
+                racks = rack_count(self.domain, avail.shape[-1])
                 for t in thetas:
                     for mk in masks:
                         mask = np.broadcast_to(
@@ -835,9 +841,10 @@ class AdaptiveReplanner:
                             mask=mask,
                             objective=objective,
                             cache=cache_spec,
+                            domain=self.domain,
                         )
                         probs.append(prob)
-                        starts.append(feasible_uniform(mask, prob.k))
+                        starts.append(feasible_uniform(mask, prob.k, racks))
                         if pi0 is not None:
                             if with_repair:
                                 start, _ = augment_plan(pi0, lam_np[:r], repair)
@@ -946,6 +953,10 @@ class AdaptiveReplanner:
                     diag.count("solver.trips", int(it.max()))
                     diag.count("solver.lanes", int(it.size))
                     self.solve_iters.append(int(it[best] if it.ndim else it))
+                if sols.rack_merges is not None:
+                    # jaxcheck: JX001 ok per-replan telemetry read after the decision sync, one transfer
+                    merges = int(np.asarray(sols.rack_merges)[best])
+                    diag.count("plan.rack_merges", merges)
                 pi_best = np.asarray(sols.pi[best])
                 self.repair_pi = pi_best[r:] if with_repair else None
         return pi_best[:r]

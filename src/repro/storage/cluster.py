@@ -41,6 +41,8 @@ class StorageNode:
     overhead_s: float  # deterministic per-chunk service floor D_j
     bandwidth_mbps: float  # effective MB/s for chunk transfer
     cost_per_chunk: float  # V_j, dollars per stored chunk
+    # failure domain (rack) index; None = the node shares no domain
+    rack: int | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,6 +52,17 @@ class Cluster:
     @property
     def m(self) -> int:
         return len(self.nodes)
+
+    @property
+    def domain(self) -> np.ndarray | None:
+        """(m,) rack index of each node, or None when no node has a rack
+        (the planner then runs without rack caps)."""
+        racks = [nd.rack for nd in self.nodes]
+        if all(r is None for r in racks):
+            return None
+        if any(r is None for r in racks):
+            raise ValueError("either every node has a rack or none has")
+        return np.asarray(racks, np.int32)
 
     @property
     def cost(self) -> Array:

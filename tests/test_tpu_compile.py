@@ -112,6 +112,63 @@ def test_fcfs_kernel_compiles_under_rollout_vmap(sds):
     assert _has_kernel(compiled)
 
 
+RACK_M = 210  # hosts of the f4 cell: 14 racks of 15
+
+
+@pytest.mark.parametrize("b,n", [(1, 2000), (2, 600)], ids=["segment", "rollout"])
+def test_fcfs_kernel_compiles_for_a_rack_cell(sds, b, n):
+    """210 node rows on sublanes: a segment of the closed loop and the
+    arbitration's two candidate lanes fit the kernel's VMEM budget."""
+    f32 = jnp.float32
+
+    def one(t, masks, service, dep0):
+        lat, _, _ = _fcfs(
+            t[None], masks[None], service[None], dep0[None], jnp.zeros_like(dep0)[None]
+        )
+        return lat[0]
+
+    compiled = (
+        jax.jit(jax.vmap(one))
+        .lower(
+            sds((b, n), f32), sds((b, n, RACK_M), jnp.bool_),
+            sds((b, n, RACK_M), f32), sds((b, RACK_M), f32),
+        )
+        .compile()
+    )
+    assert _has_kernel(compiled)
+
+
+def test_rack_capped_batched_solve_fits_v5e(sds):
+    """The f4 cell's replan solve: two candidate lanes of a 1000 x 210 plan
+    with the rack caps of 14 racks, as one program."""
+    import numpy as np
+
+    from repro.core import JLCMProblem, stack_problems
+    from repro.core.jlcm import _solve_merged_device_batch
+    from repro.storage import Cluster, StorageNode
+
+    r = 1000
+    cluster = Cluster(tuple(
+        StorageNode(f"r{d}h{h}", "cell", 0.012, 120.0, 1.0, rack=d)
+        for d in range(14) for h in range(15)
+    ))
+    prob = JLCMProblem(
+        lam=jnp.ones(r), k=jnp.full(r, 10.0), moments=cluster.moments(4.194304),
+        cost=cluster.cost, theta=2e-5, mask=jnp.ones((r, RACK_M), bool),
+        domain=cluster.domain,
+    )
+    st = stack_problems([prob, prob])
+    shaped = jax.tree.map(
+        lambda x: sds(jnp.shape(x), jnp.asarray(x).dtype),
+        (jnp.zeros((2, r, RACK_M)), st._replace(mask=None, domain=None), st.mask,
+         np.float32(1e3), np.float32(0.1), np.float32(1e-5)),
+    )
+    compiled = _solve_merged_device_batch.lower(*shaped, max_iters=400, racks=14).compile()
+    ma = compiled.memory_analysis()
+    total = ma.temp_size_in_bytes + ma.argument_size_in_bytes + ma.output_size_in_bytes
+    assert total < HBM_BYTES, total
+
+
 def test_gf256_encode_kernel_compiles_for_v5e(sds):
     """Parity rows of one object: (n-k, k) @GF (k, chunk bytes)."""
     u8 = jnp.uint8
