@@ -19,6 +19,13 @@ sum_{j in d} clip(v_j - s, 0, 1), so the rack's threshold is max(tau,
 s_d) with s_d the root of h_d(s) = 1, which does not depend on tau: one
 bisection for every s_d, then one on tau over the rows, one after the
 other.
+
+Which path runs where: the plain projection (``racks=None``) is XLA on
+every platform. The rack-capped one is the VMEM-resident Pallas kernel
+``rack_project`` (`kernels/rack_projection.py`) when the program is
+compiled for a TPU, and ``_project_racks_impl`` in XLA elsewhere, where it
+is also the kernel's oracle; a plan too wide for the kernel's row block
+takes the XLA path everywhere.
 """
 from __future__ import annotations
 
@@ -60,7 +67,7 @@ def project_capped_simplex(
     """
     if racks is None:
         return _project_impl(v, k, mask, iters=iters)
-    return _project_racks_impl(v, k, mask, iters=iters, racks=racks)
+    return _project_racks(v, k, mask, iters=iters, racks=racks)
 
 
 @functools.partial(jax.jit, static_argnames=("iters",))
@@ -100,6 +107,33 @@ def _project_impl(
     tau = 0.5 * (lo + hi)
     x = jnp.clip(vm - tau[..., None], 0.0, 1.0)
     return jnp.where(mask, x, 0.0)
+
+
+@functools.partial(jax.jit, static_argnames=("iters", "racks"))
+def _project_racks(
+    v: Array,
+    k: Array,
+    mask: Array | None,
+    *,
+    iters: int,
+    racks: int,
+) -> Array:
+    """The rack-capped projection on the platform the program is compiled
+    for: the VMEM-resident kernel on a TPU, ``_project_racks_impl``
+    elsewhere and for plans whose row block would not fit VMEM."""
+    from repro.kernels import rack_projection  # repro.kernels imports storage
+
+    v = jnp.asarray(v)
+    k = jnp.broadcast_to(jnp.asarray(k, v.dtype), v.shape[:-1])
+    if mask is None:
+        mask = jnp.ones_like(v, dtype=bool)
+    else:
+        mask = jnp.broadcast_to(jnp.asarray(mask, bool), v.shape)
+    xla = functools.partial(_project_racks_impl, iters=iters, racks=racks)
+    if not rack_projection.fits(v.shape[-1]):
+        return xla(v, k, mask)
+    kernel = functools.partial(rack_projection.rack_project_pallas, iters=iters, racks=racks)
+    return jax.lax.platform_dependent(v, k, mask, tpu=kernel, default=xla)
 
 
 @functools.partial(jax.jit, static_argnames=("iters", "racks"))

@@ -1,5 +1,5 @@
-"""TPU kernels: the GF(256) erasure-coding hot path and the fused FCFS
-fleet-queue scan."""
+"""TPU kernels: the GF(256) erasure-coding hot path, the fused FCFS
+fleet-queue scan and the planner's rack-capped projection."""
 
 from .fcfs_queue import fcfs_scan, fcfs_scan_pallas
 from .gf256_matmul import (
@@ -15,4 +15,5 @@ from .ops import (
     rs_decode,
     rs_encode,
 )
+from .rack_projection import rack_project_pallas
 from .ref import gf256_matmul_dense_ref, gf256_matmul_ref

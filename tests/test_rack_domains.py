@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 import _rack_reference as rr
+import jax
 import jax.numpy as jnp
 
 from repro.core import (
@@ -20,6 +21,8 @@ from repro.core import (
     stack_problems,
 )
 from repro.core.jlcm import RACK_GAP_TOL
+from repro.core.projection import _project_racks_impl
+from repro.kernels.rack_projection import rack_project_pallas
 from repro.storage import Cluster, StorageNode
 
 TOL = 1e-3  # the solver's SUPPORT_TOL: an entry above it stores a chunk
@@ -106,6 +109,65 @@ def test_projection_meets_kkt_conditions(seed):
             assert np.all(vs[on & (xs >= 1 - 1e-6)] - 1.0 >= t - eps)
             checked += 1
     assert checked > 100
+
+
+def kernel_case(case):
+    """(v, k, mask, racks, rows the float64 reference checks) of one case;
+    ``v`` is (lanes, r, m) for the vmapped case, else (r, m)."""
+    if case == "4x5":
+        v, k, mask = seeded_rows(0)
+        return v, k, mask, 4, slice(None)
+    if case == "5x3-r130":  # rows over two lane tiles, 3 hosts a rack
+        rng = np.random.default_rng(7)
+        v = rng.normal(0.3, 0.6, (130, 15))
+        k = rng.choice([1.0, 2.0, 3.0, 4.0], 130)
+        return v, k, np.ones(v.shape, bool), 5, slice(None)
+    if case == "14x15-r1000":  # the f4 cell's shape, rack 3 down, plan-like values
+        rng = np.random.default_rng(8)
+        v = rng.normal(0.05, 0.08, (1000, 210))
+        k = rng.choice([10.0, 11.0, 12.0, 13.0], 1000)
+        mask = np.ones(v.shape, bool)
+        mask[:, 45:60] = False
+        return v, k, mask, 14, slice(None, None, 25)
+    if case == "rack-down":  # rack 1 down everywhere, rack 2 in every third row too
+        v, k, mask = seeded_rows(1, down=1)
+        mask[::3, 10:15] = False
+        return v, np.minimum(k, 2.0), mask, 4, slice(None)
+    if case == "k-all-racks":  # k = the racks with an allowed host
+        v, _, mask = seeded_rows(2)
+        mask[::2, 5:10] = False
+        mask[1::4, 0:5] = False
+        k = mask.reshape(-1, 4, 5).any(-1).sum(-1).astype(np.float64)
+        return v, k, mask, 4, slice(None)
+    assert case == "vmap"  # two candidate lanes, as the batched solve runs it
+    (v0, k0, m0), (v1, k1, m1) = seeded_rows(3, down=0), seeded_rows(4, down=2)
+    return np.stack([v0, v1]), np.stack([k0, k1]), np.stack([m0, m1]), 4, slice(None)
+
+
+@pytest.mark.parametrize(
+    "case", ["4x5", "5x3-r130", "14x15-r1000", "rack-down", "k-all-racks", "vmap"]
+)
+def test_rack_kernel_matches_xla_and_reference(case):
+    """The VMEM-resident kernel (interpret mode) against the XLA projection
+    and the float64 reference: float32 ulps apart, within the caps, row
+    sums k, nothing on a masked host."""
+    v, k, mask, racks, rows = kernel_case(case)
+    args = (jnp.asarray(v, jnp.float32), jnp.asarray(k, jnp.float32), jnp.asarray(mask))
+    kernel = lambda v, k, m: rack_project_pallas(v, k, m, racks=racks, interpret=True)
+    xla = lambda v, k, m: _project_racks_impl(v, k, m, iters=60, racks=racks)
+    if v.ndim == 3:
+        kernel, xla = jax.vmap(kernel), jax.vmap(xla)
+    got = np.asarray(kernel(*args), np.float64)
+    np.testing.assert_allclose(got, np.asarray(xla(*args), np.float64), atol=2e-6)
+    for g, vv, kk, mm in zip(*(np.reshape(a, (-1,) + np.shape(a)[-2:]) for a in
+                               (got, v, np.expand_dims(k, -1), mask))):
+        kk = kk[:, 0]
+        want = rr.project(vv[rows], kk[rows], mm[rows], racks)
+        np.testing.assert_allclose(g[rows], want, atol=2e-6)
+        assert not g[~mm].any()
+        assert g.min() >= 0.0 and g.max() <= 1.0
+        assert rr.rack_sums(g, racks).max() <= 1.0 + 2e-6
+        assert np.max(np.abs(g.sum(-1) - kk) / np.maximum(kk, 1.0)) < 2e-6
 
 
 def test_rack_round_invariants():

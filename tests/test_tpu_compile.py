@@ -138,35 +138,70 @@ def test_fcfs_kernel_compiles_for_a_rack_cell(sds, b, n):
     assert _has_kernel(compiled)
 
 
-def test_rack_capped_batched_solve_fits_v5e(sds):
-    """The f4 cell's replan solve: two candidate lanes of a 1000 x 210 plan
-    with the rack caps of 14 racks, as one program."""
+def _batched_solve(sds, cluster, r, racks):
+    """The replan's batched solve, two candidate lanes of an (r, m) plan,
+    compiled for one v5e."""
     import numpy as np
 
     from repro.core import JLCMProblem, stack_problems
     from repro.core.jlcm import _solve_merged_device_batch
-    from repro.storage import Cluster, StorageNode
 
-    r = 1000
-    cluster = Cluster(tuple(
-        StorageNode(f"r{d}h{h}", "cell", 0.012, 120.0, 1.0, rack=d)
-        for d in range(14) for h in range(15)
-    ))
     prob = JLCMProblem(
-        lam=jnp.ones(r), k=jnp.full(r, 10.0), moments=cluster.moments(4.194304),
-        cost=cluster.cost, theta=2e-5, mask=jnp.ones((r, RACK_M), bool),
-        domain=cluster.domain,
+        lam=jnp.ones(r), k=jnp.full(r, 10.0 if racks else float(K)),
+        moments=cluster.moments(4.194304), cost=cluster.cost, theta=2e-5,
+        mask=jnp.ones((r, cluster.m), bool), domain=cluster.domain,
     )
     st = stack_problems([prob, prob])
     shaped = jax.tree.map(
         lambda x: sds(jnp.shape(x), jnp.asarray(x).dtype),
-        (jnp.zeros((2, r, RACK_M)), st._replace(mask=None, domain=None), st.mask,
+        (jnp.zeros((2, r, cluster.m)), st._replace(mask=None, domain=None), st.mask,
          np.float32(1e3), np.float32(0.1), np.float32(1e-5)),
     )
-    compiled = _solve_merged_device_batch.lower(*shaped, max_iters=400, racks=14).compile()
+    return _solve_merged_device_batch.lower(*shaped, max_iters=400, racks=racks).compile()
+
+
+def test_rack_capped_batched_solve_fits_v5e(sds):
+    """The f4 cell's replan solve: two candidate lanes of a 1000 x 210 plan
+    with the rack caps of 14 racks, as one program, its rack-capped
+    projections the VMEM-resident kernel."""
+    from repro.storage import Cluster, StorageNode
+
+    cluster = Cluster(tuple(
+        StorageNode(f"r{d}h{h}", "cell", 0.012, 120.0, 1.0, rack=d)
+        for d in range(14) for h in range(15)
+    ))
+    compiled = _batched_solve(sds, cluster, 1000, 14)
+    assert _has_kernel(compiled)
+    assert "rack_project" in compiled.as_text()
     ma = compiled.memory_analysis()
     total = ma.temp_size_in_bytes + ma.argument_size_in_bytes + ma.output_size_in_bytes
     assert total < HBM_BYTES, total
+
+
+@pytest.mark.parametrize(
+    "racks,kernel", [(14, True), (28, False)], ids=["f4-cell", "too-wide-for-vmem"]
+)
+def test_rack_projection_takes_the_kernel_where_it_fits(sds, racks, kernel):
+    """Racks of 15 hosts over 1000 rows: 14 racks run the VMEM-resident
+    kernel; 28 (420 hosts) would overflow its row block and stay in XLA."""
+    from repro.core import project_capped_simplex
+
+    r, m = 1000, racks * 15
+    compiled = (
+        jax.jit(lambda v, k, mask: project_capped_simplex(v, k, mask, racks=racks))
+        .lower(sds((r, m), jnp.float32), sds((r,), jnp.float32), sds((r, m), jnp.bool_))
+        .compile()
+    )
+    assert _has_kernel(compiled) == kernel
+
+
+def test_plain_batched_solve_runs_no_kernel(sds):
+    """Tahoe's replan solve (no racks) keeps the plain XLA projection: the
+    rack kernel is bypassed."""
+    from repro.storage import tahoe_testbed
+
+    compiled = _batched_solve(sds, tahoe_testbed(), 1000, None)
+    assert not _has_kernel(compiled)
 
 
 def test_gf256_encode_kernel_compiles_for_v5e(sds):
