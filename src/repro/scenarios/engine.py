@@ -68,9 +68,10 @@ provisioned hot tier) join the outcome.
 Geo scenarios (``spec.sites`` set) run through :func:`run_geo_scenario`
 against the 4-client-site fabric: per-(client-site, node) service
 sampling, a per-segment client-population mix schedule, optional egress
-degradation — and a geo-aware closed loop (``GeoAdaptiveReplanner``)
-whose static baseline is deliberately *geo-oblivious* (the paper's
-single-implicit-client plan). See that function's docstring.
+degradation — and a geo-aware closed loop (``AdaptiveReplanner`` fed the
+(C, r) site x file rates) whose static baseline is deliberately
+*geo-oblivious* (the paper's single-implicit-client plan). See that
+function's docstring.
 """
 from __future__ import annotations
 
@@ -94,7 +95,6 @@ from repro.serving import (
     AdaptiveReplanner,
     EwmaMomentEstimator,
     EwmaRateEstimator,
-    GeoAdaptiveReplanner,
     HierarchicalReplanner,
 )
 from repro.storage import (
@@ -702,9 +702,10 @@ def run_geo_scenario(
       the operating model the ISSUE's motivation calls out.
     * ``oblivious`` — rate-proportional dispatch, as before.
     * ``adaptive`` — the geo closed loop: per-(site, node) moment EWMA +
-      per-(site, file) rate EWMA feeding ``GeoAdaptiveReplanner``, which
-      re-solves *geo* problems (estimated pair moments + estimated client
-      mix) so placement follows the active client population.
+      per-(site, file) rate EWMA feeding ``AdaptiveReplanner`` the (C, r)
+      rate matrix, which re-solves *geo* problems (estimated pair moments
+      + estimated client mix) so placement follows the active client
+      population.
 
     All policies simulate against the same fabric ground truth: per-pair
     service sampling, the spec's mix schedule, and its egress-degradation
@@ -751,7 +752,7 @@ def run_geo_scenario(
     else:
         moment_est = EwmaMomentEstimator(prior=fabric.moments(spec.chunk_mb))
         rate_est = EwmaRateEstimator(prior=lam_cs_seq[0].reshape(-1))
-        replanner = GeoAdaptiveReplanner(
+        replanner = AdaptiveReplanner(
             k=np.asarray(spec.k),
             cost=np.asarray(fabric.cluster.cost),
             theta=spec.theta,

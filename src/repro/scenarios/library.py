@@ -170,7 +170,7 @@ GEO_CLIENT_SHIFT = register(
         "load-shift modeling) changes the optimal placement, not just "
         "the constants. Exercises core/geo.py end to end: pair moments "
         "through the solver, estimated client mix through "
-        "GeoAdaptiveReplanner.",
+        "the replanner's (C, r) geo case.",
         expected="the static geo-oblivious plan (solved from the "
         "single-implicit-NJ-client view) keeps dispatching to "
         "NJ-favoring placements after the population has moved west and "

@@ -5,7 +5,6 @@ from .router import (
     AdaptiveReplanner,
     EwmaMomentEstimator,
     EwmaRateEstimator,
-    GeoAdaptiveReplanner,
     HierarchicalReplanner,
     ReplicaPool,
     Router,

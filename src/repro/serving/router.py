@@ -18,19 +18,18 @@ service observations (``storage.simulator.NodeObservations``) into EWMA
 estimates of the Lemma-3 moments, :class:`EwmaRateEstimator` tracks the
 per-class arrival rates the same way, and :class:`AdaptiveReplanner`
 re-solves JLCM from those *estimated* inputs — batching all candidate
-(theta, availability-mask) re-plans into one ``solve_batch`` call — to
-produce the next segment's dispatch matrix. Candidate *arbitration* is
-equally batched: :func:`batched_rollout_scores` fuses every candidate's
-exact-simulator rollout, its composed-objective scoring, the
-``+ theta * cost`` fold, and the winning ``argmin`` into ONE compiled
-device program (candidate axis padded to a power of two for program
-reuse, optional common-random-number seed axis, ``shard_map`` over the
-local mesh when >1 device) with a single host sync per replan. `src/repro/scenarios/` wires
-this loop against the segmented simulator. :class:`GeoAdaptiveReplanner`
-is the client-fabric variant: it estimates the full (C, m) per-(client-
-site, node) service family and the (C, r) traffic matrix, and re-solves
-*geo* problems so placement follows the active client population
-(`core/geo.py`).
+(availability-mask x cold/warm start) re-plans into one ``solve_batch``
+call — to produce the next segment's dispatch matrix. Candidate
+*arbitration* is equally batched: :func:`batched_rollout_scores` fuses
+every candidate's exact-simulator rollout, its composed-objective
+scoring, the ``+ theta * cost`` fold, and the winning ``argmin`` into ONE
+compiled device program (candidate axis padded to a power of two for
+program reuse, optional common-random-number seed axis, ``shard_map``
+over the local mesh when >1 device) with a single host sync per replan.
+The client-fabric (geo) loop is the same replanner fed a (C, r) site x
+file rate matrix and (C, m) pair moments: it re-solves *geo* problems so
+placement follows the active client population (`core/geo.py`).
+`src/repro/scenarios/` wires this loop against the segmented simulator.
 """
 from __future__ import annotations
 
@@ -50,10 +49,10 @@ from repro.core import (
     ObjectiveSpec,
     ServiceMoments,
     build_problem,
-    empirical_objective,
     empirical_objective_device,
     feasible_uniform,
     fit_shifted_exponential,
+    geo_problem,
     madow_sample,
     materialize,
     project_capped_simplex,
@@ -493,7 +492,7 @@ def batched_rollout_scores(
 ):
     """Score a (B, r, m) candidate-plan stack in ONE device program.
 
-    The replanners' arbitration hot path, public so benchmarks and parity
+    The replanner's arbitration hot path, public so benchmarks and parity
     tests drive the exact production surface
     (`benchmarks/replan_wall.py`, ``tests/test_replan_batch.py``). The
     candidate axis is padded to a power of two (padded lanes replay
@@ -503,7 +502,7 @@ def batched_rollout_scores(
     ``rollout_seeds == 1`` the key is used UNSPLIT (``key[None]``), which
     makes each candidate's simulated latency stream bitwise identical to
     a sequential ``run_segment_raw(carry, key, pi_i, ...)`` call — the
-    legacy loop's common-random-number contract; ``rollout_seeds > 1``
+    parity tests' sequential reference; ``rollout_seeds > 1``
     splits the key once and scores each candidate by its K-seed mean.
     ``devices="auto"`` shards the (candidate x seed) lanes over all local
     devices when >1 (growing the pad until the lane count divides the
@@ -566,14 +565,13 @@ class AdaptiveReplanner:
 
     Holds the pieces of the control loop that face the solver: the catalog
     shape (``k``, per-node ``cost``), the operating tradeoff ``theta``, and
-    the moment estimator. :meth:`replan` builds the candidate set — the
-    cross product of ``thetas`` (defaults to the operating theta) and
-    candidate availability masks (defaults to the health-check mask alone),
-    each solved from BOTH a cold (feasible-uniform) and, when the current
-    plan is supplied, a warm start — and solves them all in ONE
-    ``solve_batch`` call. With the defaults that is two masked re-solves in
-    one XLA program, exactly the shape ``Router.precompute_failover``
-    batches over hypothetical failures.
+    the moment estimator. :meth:`replan` builds the candidate set — each
+    candidate availability mask (defaults to the health-check mask alone)
+    solved from BOTH a cold (feasible-uniform) and, when the current plan
+    is supplied, a warm start — and solves them all in ONE ``solve_batch``
+    call. With the defaults that is two masked re-solves in one XLA
+    program, exactly the shape ``Router.precompute_failover`` batches over
+    hypothetical failures.
 
     Candidate selection is *model-predictive* when the caller supplies the
     live queue state: each candidate plan is scored by a short exact-
@@ -593,13 +591,12 @@ class AdaptiveReplanner:
     Rollout arbitration runs as ONE compiled device program
     (:func:`batched_rollout_scores`): candidates vmap over the rollout,
     scores fold the device empirical objective plus ``theta * cost``, and
-    only the winning index crosses to the host — at ``rollout_seeds=1``
-    (the default) bit-identical in its chosen plan to the sequential
-    per-candidate loop (``rollout_batched=False``) it replaced, and at
-    ``rollout_seeds=K`` averaging K common-random-number rollouts per
-    candidate for variance-reduced selection at near-flat wall.
-    Per-replan arbitration wall time lands in :attr:`rollout_walls`
-    (surfaced as the scenario CSVs' ``rollout_wall_ms`` column).
+    only the winning index crosses to the host. Each candidate replays the
+    same unsplit ``key``, so the chosen plan is the argmin of per-candidate
+    sequential rollouts (``tests/test_replan_batch.py`` holds that
+    reference). Per-replan arbitration wall time lands in
+    :attr:`rollout_walls` (surfaced as the scenario CSVs'
+    ``rollout_wall_ms`` column).
 
     Warm starts track slow drift with fewer iterations (DC programming
     keeps support); cold starts escape a stale support after abrupt
@@ -612,6 +609,19 @@ class AdaptiveReplanner:
     scoring applies the SAME objective to the simulated latencies
     (``core.objectives.empirical_objective``) — so a premium class is
     protected by the *selection* step too, e.g. during node failures.
+
+    Geo (client fabric, ``core/geo.py``): a (C, r) ``class_rates`` — the
+    site x file arrival matrix an :class:`EwmaRateEstimator` over
+    flattened (site, file) ids tracks — makes every candidate a geo
+    problem (``core.geo.geo_problem``). Its column sums are the catalog
+    rates and its normalized columns the per-file client mix, which is how
+    a migrating population ("follow the sun") enters the solver; the
+    estimator is then seeded with the fabric's (C, m) per-(client-site,
+    node) moments and fed the geo simulator's per-pair observations
+    (elementwise, so it tracks the pair family unchanged — cross-site
+    egress degradation is a row pattern no per-node estimate could hold).
+    Rollouts run the geo segment kernel on the (C, r) matrix. The geo case
+    takes no cache, repair flow or failure domains.
 
     Repair awareness (``storage/repair.py``): passing a ``RepairFlow`` to
     :meth:`replan` folds reconstruction traffic into every candidate —
@@ -649,22 +659,10 @@ class AdaptiveReplanner:
     k: np.ndarray  # (r,) MDS k_i per class/file
     cost: np.ndarray  # (m,) per-node cost V_j
     theta: float
-    estimator: EwmaMomentEstimator
+    estimator: EwmaMomentEstimator  # (m,) moments, or (C, m) for geo
     objective: ObjectiveSpec | None = None  # scenario's composed objective
-    thetas: tuple[float, ...] | None = None
     max_iters: int = 400
     rollout_requests: int = 600
-    # common-random-number rollout seeds per candidate (K): 1 keeps the
-    # historical bitwise stream (unsplit key), >1 scores each candidate by
-    # its K-seed mean — variance-reduced arbitration at near-flat wall
-    rollout_seeds: int = 1
-    # False restores the legacy per-candidate Python loop (one device
-    # dispatch + host sync per candidate); kept as the parity/benchmark
-    # baseline the batched arbitration is asserted bit-identical against
-    rollout_batched: bool = True
-    # mesh policy for batched rollouts: "auto" shards (candidate x seed)
-    # lanes over all local devices when >1, "never" forces plain vmap
-    rollout_devices: str = "auto"
     replans: int = 0
     # optimized reconstruction-read dispatch from the last repair-aware
     # replan (None when the last replan saw no active repair flow)
@@ -754,7 +752,8 @@ class AdaptiveReplanner:
     ) -> np.ndarray:
         """New (r, m) dispatch matrix from estimated moments + health mask.
 
-        ``pi0`` (the plan currently dispatching) adds warm-started
+        ``class_rates`` is (r,), or (C, r) site x file rates for a geo
+        replan. ``pi0`` (the plan currently dispatching) adds warm-started
         candidates; ``carry`` (``storage.simulator.SimCarry``) plus a PRNG
         ``key`` switch scoring to predictive rollouts from the live queue
         state. ``repair`` (a ``storage.repair.RepairFlow``) folds known
@@ -769,16 +768,31 @@ class AdaptiveReplanner:
         from repro.storage.cache import che_hit_rates
         from repro.storage.repair import augment_plan
 
+        with_repair = repair is not None and repair.active
+        geo = np.ndim(class_rates) == 2
+        if geo and (self.cache is not None or with_repair or self.domain is not None):
+            raise ValueError(
+                "a (C, r) geo replan takes no cache, repair flow or failure domains"
+            )
         with diag.span("replan.step"):
             with diag.span("replan.estimate"):
                 r = int(np.asarray(self.k).shape[0])
                 avail = np.asarray(avail, bool)
                 masks = [avail] if candidate_masks is None else candidate_masks
-                thetas = (self.theta,) if self.thetas is None else tuple(self.thetas)
                 mom = self.estimator.moments()
-                with_repair = repair is not None and repair.active
                 k_vec = np.asarray(self.k, np.float32)
                 lam_np = np.asarray(class_rates, np.float64)
+                if geo:
+                    lam_cs, lam_np = lam_np, lam_np.sum(axis=0)
+                    # a file observed at (essentially) zero rate has no
+                    # empirical mix; give it the population-average mix
+                    # rather than 0/0
+                    pop = lam_cs.sum(axis=1)
+                    pop_mix = pop / max(pop.sum(), 1e-12)
+                    safe = np.maximum(lam_np, 1e-12)
+                    mix = np.where(
+                        (lam_np > 1e-12)[:, None], (lam_cs / safe).T, pop_mix[None, :]
+                    )
                 cache_spec = None
                 ttl_plan = None
                 if self.cache is not None:
@@ -818,40 +832,51 @@ class AdaptiveReplanner:
                         hot_cost=self.cache.hot_cost(),
                     )
                 lam = jnp.asarray(lam_np, jnp.float32)
+                # geo rollouts draw each request's site from the (C, r) matrix
+                roll_lam = jnp.asarray(lam_cs, jnp.float32) if geo else lam
                 objective = self._repair_objective() if with_repair else self.objective
             with diag.span("replan.assemble"):
                 probs, starts = [], []
                 racks = rack_count(self.domain, avail.shape[-1])
-                for t in thetas:
-                    for mk in masks:
-                        mask = np.broadcast_to(
-                            np.asarray(mk, bool), (r, avail.shape[-1])
+                for mk in masks:
+                    mask = np.broadcast_to(np.asarray(mk, bool), (r, avail.shape[-1]))
+                    if with_repair:
+                        mask = np.concatenate(
+                            [mask, np.asarray(repair.mask, bool)], axis=0
                         )
-                        if with_repair:
-                            mask = np.concatenate(
-                                [mask, np.asarray(repair.mask, bool)], axis=0
-                            )
-                        mask = jnp.asarray(mask)
+                    mask = jnp.asarray(mask)
+                    if geo:
+                        prob = geo_problem(
+                            lam,
+                            jnp.asarray(k_vec),
+                            mom,
+                            mix,
+                            jnp.asarray(self.cost, jnp.float32),
+                            float(self.theta),
+                            mask=mask,
+                            objective=objective,
+                        )
+                    else:
                         prob = JLCMProblem(
                             lam=lam,
                             k=jnp.asarray(k_vec),
                             moments=mom,
                             cost=jnp.asarray(self.cost, jnp.float32),
-                            theta=float(t),
+                            theta=float(self.theta),
                             mask=mask,
                             objective=objective,
                             cache=cache_spec,
                             domain=self.domain,
                         )
+                    probs.append(prob)
+                    starts.append(feasible_uniform(mask, prob.k, racks))
+                    if pi0 is not None:
+                        if with_repair:
+                            start, _ = augment_plan(pi0, lam_np[:r], repair)
+                        else:
+                            start = np.asarray(pi0)
                         probs.append(prob)
-                        starts.append(feasible_uniform(mask, prob.k, racks))
-                        if pi0 is not None:
-                            if with_repair:
-                                start, _ = augment_plan(pi0, lam_np[:r], repair)
-                            else:
-                                start = np.asarray(pi0)
-                            probs.append(prob)
-                            starts.append(jnp.asarray(start, jnp.float32))
+                        starts.append(jnp.asarray(start, jnp.float32))
             with diag.span("replan.solve") as solve_span:
                 sols = solve_batch(probs, max_iters=self.max_iters, pi0=jnp.stack(starts))
                 with diag.span("replan.solve_wait"):
@@ -880,66 +905,30 @@ class AdaptiveReplanner:
                             carry = carry._replace(
                                 cache=jnp.full(ttl_roll.shape, -jnp.inf)
                             )
-                    if self.rollout_batched:
-                        # every candidate rolled out + scored (the same composed
-                        # empirical objective as the sequential loop, repair rows
-                        # masked out) + cost-folded + argmin'd in ONE compiled
-                        # device program; int(best) below is the replan's single
-                        # host sync
-                        scores, best_dev = batched_rollout_scores(
-                            carry,
-                            key,
-                            sols.pi,
-                            lam,
-                            jnp.asarray(d, jnp.float32),
-                            jnp.asarray(srv_rates, jnp.float32),
-                            jnp.asarray(avail),
-                            self.theta * sols.cost,  # device-side cost fold
-                            self.objective,
-                            n_clients=r,
-                            n_requests=self.rollout_requests,
-                            rollout_seeds=self.rollout_seeds,
-                            ttl=ttl_roll,
-                            hit_latency=0.0 if hit_lat is None else hit_lat,
-                            devices=self.rollout_devices,
-                        )
-                        with diag.span("replan.sync"):
-                            # jaxcheck: JX001 ok the ONE host sync per replan (arbitration argmin)
-                            best = int(best_dev)
-                        self.last_scores = scores[: len(probs)]
-                    else:
-                        from repro.storage.simulator import run_segment_raw
-
-                        cost_term = self.theta * np.asarray(sols.cost)
-                        scores = []
-                        for i in range(len(probs)):
-                            _, res = run_segment_raw(
-                                carry,
-                                key,
-                                sols.pi[i],
-                                lam,
-                                jnp.asarray(d, jnp.float32),
-                                jnp.asarray(srv_rates, jnp.float32),
-                                jnp.asarray(avail),
-                                self.rollout_requests,
-                                ttl_roll,
-                                0.0 if hit_lat is None else hit_lat,
-                            )
-                            lat_np = np.asarray(res.latency)
-                            fid_np = np.asarray(res.file_id)
-                            if with_repair:  # score client traffic only
-                                client = fid_np < r
-                                lat_np, fid_np = lat_np[client], fid_np[client]
-                            # same objective as the analytic fallback, with the
-                            # empirical composed objective (weighted mean + per-
-                            # class exceedance frequencies) replacing the loose,
-                            # backlog-blind analytic bound
-                            scores.append(
-                                empirical_objective(lat_np, fid_np, self.objective)
-                                + float(cost_term[i])
-                            )
-                        best = int(np.argmin(scores))
-                        self.last_scores = np.asarray(scores)
+                    # every candidate rolled out + scored (the composed
+                    # empirical objective, repair rows masked out) +
+                    # cost-folded + argmin'd in ONE compiled device program;
+                    # int(best) below is the replan's single host sync
+                    scores, best_dev = batched_rollout_scores(
+                        carry,
+                        key,
+                        sols.pi,
+                        roll_lam,
+                        jnp.asarray(d, jnp.float32),
+                        jnp.asarray(srv_rates, jnp.float32),
+                        jnp.asarray(avail),
+                        self.theta * sols.cost,  # device-side cost fold
+                        self.objective,
+                        n_clients=r,
+                        n_requests=self.rollout_requests,
+                        ttl=ttl_roll,
+                        hit_latency=0.0 if hit_lat is None else hit_lat,
+                        geo=geo,
+                    )
+                    with diag.span("replan.sync"):
+                        # jaxcheck: JX001 ok the ONE host sync per replan (arbitration argmin)
+                        best = int(best_dev)
+                    self.last_scores = scores[: len(probs)]
                 self.rollout_walls.append(arb_span.seconds)
             else:
                 cost_term = self.theta * np.asarray(sols.cost)
@@ -1077,7 +1066,7 @@ class HierarchicalReplanner:
                 )
                 # device argmin: transfer the winning index, not the whole
                 # objective vector (the same one-sync contract the rollout
-                # replanners' batched arbitration keeps)
+                # replanner's batched arbitration keeps)
                 best = int(jnp.argmin(sols.objective))
                 self.plan = FactoredPlan(
                     h, jnp.asarray(sols.pi[best]), lam_c.copy()
@@ -1106,191 +1095,6 @@ class HierarchicalReplanner:
         self.solve_iters.append(iters)
         self.replans += 1
         return pi
-
-
-@dataclasses.dataclass
-class GeoAdaptiveReplanner:
-    """Geo-aware closed loop: re-place chunks toward the active client site.
-
-    The geo twin of :class:`AdaptiveReplanner`. Its estimated state is one
-    dimension richer on both axes of the loop:
-
-    * **moments** — the :class:`EwmaMomentEstimator` is seeded with the
-      fabric's (C, m) per-(client-site, node) moments and fed the geo
-      simulator's per-pair observations (``GeoSegmentResult.obs``, every
-      field (C, m)); the estimator is elementwise, so it tracks the full
-      pair family unchanged. Cross-site egress degradation shows up as a
-      *row-pattern* drift no per-node estimate could represent.
-    * **rates** — an :class:`EwmaRateEstimator` over flattened
-      (site, file) ids tracks the (C, r) arrival matrix; its column sums
-      are the catalog rates and its normalized rows the per-file client
-      mix, which is how a migrating population ("follow the sun") enters
-      the solver.
-
-    Each :meth:`replan` builds geo problems (``core.geo.geo_problem``)
-    from those estimates — per-pair moments AND mix, so the solve trades
-    locality against storage cost — for the same warm/cold x theta x mask
-    candidate grid as :meth:`AdaptiveReplanner.replan` (the grid-build /
-    warm-start / score-and-argmin conventions deliberately mirror that
-    method; a change to either candidate loop should be applied to both),
-    in ONE ``solve_batch`` call
-    (the ``GeoSpec`` is a pytree: a candidate sweep over client mixes is
-    a single vmapped program). Candidates are arbitrated by geo rollouts
-    from the live queue state — batched like the plain loop
-    (:func:`batched_rollout_scores` with the geo segment kernel) and
-    scored under the composed empirical ``objective`` (tenant weights and
-    deadlines bind geo arbitration exactly as they bind solves), falling
-    back to the analytic composed bound when no ``carry``/``key`` is
-    given.
-    """
-
-    k: np.ndarray  # (r,) MDS k_i per file
-    cost: np.ndarray  # (m,) per-node cost V_j
-    theta: float
-    estimator: EwmaMomentEstimator  # prior/updates carry (C, m) arrays
-    # tenant mix: candidate solves optimize the composed geo objective and
-    # rollout arbitration scores candidates under the SAME spec (shared
-    # device empirical objective) — geo replans honor per-class weights
-    # and deadlines exactly like the non-geo loop
-    objective: ObjectiveSpec | None = None
-    thetas: tuple[float, ...] | None = None
-    max_iters: int = 400
-    rollout_requests: int = 600
-    # batched-arbitration knobs; see AdaptiveReplanner for semantics
-    rollout_seeds: int = 1
-    rollout_batched: bool = True
-    rollout_devices: str = "auto"
-    replans: int = 0
-    # per-replan solver telemetry (mirrors AdaptiveReplanner)
-    solve_iters: list = dataclasses.field(default_factory=list)
-    solve_walls: list = dataclasses.field(default_factory=list)
-    rollout_walls: list = dataclasses.field(default_factory=list)
-    last_scores: Any = None
-
-    def replan(
-        self,
-        lam_cs: np.ndarray,
-        avail: np.ndarray,
-        *,
-        candidate_masks: list[np.ndarray] | None = None,
-        pi0: np.ndarray | None = None,
-        carry: Any | None = None,
-        key: Any | None = None,
-    ) -> np.ndarray:
-        """New (r, m) dispatch matrix from the estimated (C, r) traffic
-        matrix plus the health mask. All inputs are measured/estimated —
-        ground truth never enters (availability is the health-checker
-        input, same detection model as the plain loop)."""
-        from repro.core import geo_problem
-
-        lam_cs = np.asarray(lam_cs, np.float64)
-        c, r = lam_cs.shape
-        avail = np.asarray(avail, bool)
-        lam = lam_cs.sum(axis=0)
-        # a file observed at (essentially) zero rate has no empirical mix;
-        # give it the population-average mix rather than 0/0
-        pop = lam_cs.sum(axis=1)
-        pop_mix = pop / max(pop.sum(), 1e-12)
-        safe = np.maximum(lam, 1e-12)
-        mix = np.where(
-            (lam > 1e-12)[:, None], (lam_cs / safe).T, pop_mix[None, :]
-        )
-        site_mom = self.estimator.moments()  # ServiceMoments, (C, m) arrays
-
-        masks = [avail] if candidate_masks is None else candidate_masks
-        thetas = (self.theta,) if self.thetas is None else tuple(self.thetas)
-        probs, starts = [], []
-        for t in thetas:
-            for mk in masks:
-                mask = jnp.asarray(
-                    np.broadcast_to(np.asarray(mk, bool), (r, avail.shape[-1]))
-                )
-                prob = geo_problem(
-                    jnp.asarray(lam, jnp.float32),
-                    jnp.asarray(self.k, jnp.float32),
-                    site_mom,
-                    mix,
-                    jnp.asarray(self.cost, jnp.float32),
-                    float(t),
-                    mask=mask,
-                    objective=self.objective,
-                )
-                probs.append(prob)
-                starts.append(feasible_uniform(mask, prob.k))
-                if pi0 is not None:
-                    probs.append(prob)
-                    starts.append(jnp.asarray(np.asarray(pi0), jnp.float32))
-        with diag.span("replan.solve") as solve_span:
-            sols = solve_batch(probs, max_iters=self.max_iters, pi0=jnp.stack(starts))
-            with diag.span("replan.solve_wait"):
-                jax.block_until_ready(sols.pi)
-        self.solve_walls.append(solve_span.seconds)
-        self.replans += 1
-
-        if carry is not None and key is not None:
-            d, srv_rates = self.estimator.fitted_shifted_exp()  # (C, m) each
-            lam_cs_j = jnp.asarray(lam_cs, jnp.float32)
-            with diag.span("replan.arbitrate") as arb_span:
-                if self.rollout_batched:
-                    # geo twin of the fused arbitration: all candidates rolled
-                    # out, scored under the composed empirical objective (NOT
-                    # a bare latency mean — tenant weights/deadlines bind geo
-                    # arbitration too), cost-folded, and argmin'd on device
-                    scores, best_dev = batched_rollout_scores(
-                        carry,
-                        key,
-                        sols.pi,
-                        lam_cs_j,
-                        jnp.asarray(d, jnp.float32),
-                        jnp.asarray(srv_rates, jnp.float32),
-                        jnp.asarray(avail),
-                        self.theta * sols.cost,  # device-side cost fold
-                        self.objective,
-                        n_clients=r,
-                        n_requests=self.rollout_requests,
-                        rollout_seeds=self.rollout_seeds,
-                        devices=self.rollout_devices,
-                        geo=True,
-                    )
-                    # jaxcheck: JX001 ok the ONE host sync per replan (arbitration argmin)
-                    best = int(best_dev)
-                    self.last_scores = scores[: len(probs)]
-                else:
-                    from repro.storage.simulator import run_geo_segment_raw
-
-                    cost_term = self.theta * np.asarray(sols.cost)
-                    scores = []
-                    for i in range(len(probs)):
-                        _, res = run_geo_segment_raw(
-                            carry,
-                            key,
-                            sols.pi[i],
-                            lam_cs_j,
-                            jnp.asarray(d, jnp.float32),
-                            jnp.asarray(srv_rates, jnp.float32),
-                            jnp.asarray(avail),
-                            self.rollout_requests,
-                        )
-                        scores.append(
-                            empirical_objective(
-                                np.asarray(res.latency),
-                                np.asarray(res.file_id),
-                                self.objective,
-                            )
-                            + float(cost_term[i])
-                        )
-                    best = int(np.argmin(scores))
-                    self.last_scores = np.asarray(scores)
-            self.rollout_walls.append(arb_span.seconds)
-        else:
-            cost_term = self.theta * np.asarray(sols.cost)
-            scores = (np.asarray(sols.latency_tight) + cost_term).tolist()
-            best = int(np.argmin(scores))
-            self.last_scores = np.asarray(scores)
-        if sols.iterations is not None:
-            it = np.asarray(sols.iterations)
-            self.solve_iters.append(int(it[best] if it.ndim else it))
-        return np.asarray(sols.pi[best])
 
 
 def simulate_serving(

@@ -159,7 +159,6 @@ class TestClosedLoopContract:
             estimator=EwmaMomentEstimator(prior=cluster.moments(CHUNK_MB)),
             max_iters=60,
             rollout_requests=120,
-            rollout_batched=True,
         )
         avail = np.ones(cluster.m, bool)
         carry = init_carry(cluster.m)
@@ -294,6 +293,45 @@ class TestSpansAndCounters:
         solve = [e[2] - e[1] for e in events if e[0] == "replan.solve"]
         for wall, ns in zip(out.solve_walls, solve):
             assert wall * 1e9 <= ns + 1e3  # perf_counter runs inside the span
+
+    def test_geo_replan_records_step_and_trips(self, tmp_path, monkeypatch):
+        """A (C, r) geo replan runs inside the same spans and counters as a
+        plain one: the step, its phases and the solver's trip count."""
+        from repro.serving import router
+        from repro.storage import geo_testbed
+
+        fabric = geo_testbed()
+        rp = AdaptiveReplanner(
+            k=K4.copy(),
+            cost=np.asarray(fabric.cluster.cost),
+            theta=2.0,
+            estimator=EwmaMomentEstimator(prior=fabric.moments(12.5)),
+            max_iters=60,
+            rollout_requests=120,
+        )
+        orig = router.solve_batch
+        sols = []
+
+        def capture(probs, **kw):
+            sols.append(orig(probs, **kw))
+            return sols[-1]
+
+        monkeypatch.setattr(router, "solve_batch", capture)
+        lam_cs = np.asarray(fabric.uniform_mix(4)).T * LAM
+        with jax.profiler.trace(str(tmp_path)):
+            rp.replan(
+                lam_cs, np.ones(fabric.m, bool),
+                carry=init_carry(fabric.m), key=jax.random.key(5),
+            )
+        events = _host_events(tmp_path)
+        names = {e[0] for e in events}
+        assert {
+            "replan.step", "replan.estimate", "replan.assemble",
+            "replan.solve", "replan.arbitrate", "replan.sync",
+            "replan.deploy", "solver.trips", "solver.lanes",
+        } <= names
+        trips = [e[3]["value"] for e in events if e[0] == "solver.trips"]
+        assert trips == [int(np.max(np.asarray(sols[0].iterations)))]
 
     def test_no_trace_leaves_nothing_behind(self, tmp_path):
         before = set(diag.hot_path_registry())

@@ -21,7 +21,7 @@ from repro.core import (
     solve_batch,
 )
 from repro.scenarios import get_scenario, run_geo_scenario, scenario_names
-from repro.serving import EwmaMomentEstimator, GeoAdaptiveReplanner
+from repro.serving import AdaptiveReplanner, EwmaMomentEstimator
 from repro.storage import (
     ClientSite,
     GeoFabric,
@@ -417,7 +417,7 @@ class TestGeoScenarios:
 class TestGeoReplanner:
     def test_replan_shapes_and_mask(self, fabric):
         est = EwmaMomentEstimator(prior=fabric.moments(12.5))
-        rp = GeoAdaptiveReplanner(
+        rp = AdaptiveReplanner(
             k=np.asarray(K),
             cost=np.asarray(fabric.cluster.cost),
             theta=2.0,
@@ -432,6 +432,41 @@ class TestGeoReplanner:
         assert (pi[:, 0] <= 1e-6).all()
         np.testing.assert_allclose(pi.sum(-1), np.asarray(K), atol=1e-2)
         assert rp.replans == 1
+
+    @pytest.mark.parametrize("extra", ["cache", "repair", "domain"])
+    def test_geo_replan_rejects_cache_repair_and_domains(self, fabric, extra):
+        """The (C, r) geo case takes no hot tier, repair flow or failure
+        domains: asking for one raises before any state changes."""
+        from repro.storage import CacheModel, build_repair_flow
+
+        avail = np.ones((fabric.m,), bool)
+        kw, fields = {}, {}
+        if extra == "cache":
+            fields["cache"] = CacheModel(
+                file_bytes=np.full((4,), 50.0 * 2**20),
+                capacity_bytes=100.0 * 2**20,
+                hit_latency=0.5,
+                hot_price_per_mb=0.02,
+            )
+        elif extra == "repair":
+            placement = np.ones((4, fabric.m), bool)
+            avail[0] = False
+            kw["repair"] = build_repair_flow(placement, np.asarray(K), avail, 0.05)
+            assert kw["repair"].active
+        else:
+            fields["domain"] = np.arange(fabric.m) // (fabric.m // 3)
+        rp = AdaptiveReplanner(
+            k=np.asarray(K),
+            cost=np.asarray(fabric.cluster.cost),
+            theta=2.0,
+            estimator=EwmaMomentEstimator(prior=fabric.moments(12.5)),
+            max_iters=20,
+            **fields,
+        )
+        lam_cs = np.asarray(fabric.uniform_mix(4)).T * np.asarray(LAM)
+        with pytest.raises(ValueError, match="geo"):
+            rp.replan(lam_cs, avail, **kw)
+        assert rp.replans == 0 and rp.last_raw is None
 
     def test_estimator_tracks_pair_moments_from_geo_obs(self, fabric):
         """Seeded with a wrong prior, the (C, m) EWMA converges toward the

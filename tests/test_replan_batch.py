@@ -9,9 +9,11 @@ Four layers of trust, each asserted independently:
 2. **Device objective parity** — ``empirical_objective_device`` against
    the host numpy ``empirical_objective`` it mirrors, with and without a
    composed multi-tenant spec, including the repair-row validity mask.
-3. **Arbitration parity** — ``batched_rollout_scores`` and the three
-   replanners against the legacy sequential loop
-   (``rollout_batched=False``): same chosen plan (bitwise deployed pi),
+3. **Arbitration parity** — ``batched_rollout_scores`` and the
+   replanner against a sequential reference kept here: one
+   ``run_segment_raw`` / ``run_geo_segment_raw`` rollout per candidate,
+   scored by the host ``empirical_objective`` plus ``theta * cost`` on
+   client rows. Same chosen plan (bitwise deployed pi and repair pi),
    matching per-candidate scores, across plain / cache-aware /
    repair-augmented / geo replans — plus one-compiled-program reuse
    across varying candidate counts (the power-of-two lane padding).
@@ -40,8 +42,8 @@ from repro.core import (
 from repro.serving import (
     AdaptiveReplanner,
     EwmaMomentEstimator,
-    GeoAdaptiveReplanner,
     batched_rollout_scores,
+    router,
 )
 from repro.serving.router import _arbitrate_device, _pow2
 from repro.storage import (
@@ -332,41 +334,92 @@ def _estimator(cluster):
     return EwmaMomentEstimator(prior=cluster.moments(CHUNK_MB))
 
 
-def _pair(cluster, **kw):
-    """Two identical replanners, one batched, one on the legacy loop."""
-    mk = lambda batched: AdaptiveReplanner(
+def _replanner(cost, estimator, **kw):
+    return AdaptiveReplanner(
         k=K4.copy(),
-        cost=np.asarray(cluster.cost),
+        cost=np.asarray(cost),
         theta=2.0,
-        estimator=_estimator(cluster),
+        estimator=estimator,
         max_iters=80,
         rollout_requests=N_REQ,
-        rollout_batched=batched,
         **kw,
     )
-    return mk(True), mk(False)
+
+
+@pytest.fixture
+def sequential(monkeypatch):
+    """Score every batched arbitration's candidates one at a time.
+
+    Wraps ``router.batched_rollout_scores`` to capture its arguments and,
+    per candidate, runs one sequential rollout (``run_segment_raw`` or
+    ``run_geo_segment_raw``) from the same queue state and key, scores its
+    client rows by the host ``empirical_objective`` and adds the
+    candidate's ``theta * cost``. Each call appends ``(pi_stack, scores)``.
+    """
+    calls = []
+    orig = router.batched_rollout_scores
+
+    def spy(carry, key, pi_stack, lam, d, rates, avail, cost_term,
+            objective=None, **kw):
+        out = orig(carry, key, pi_stack, lam, d, rates, avail, cost_term,
+                   objective, **kw)
+        cost = np.asarray(cost_term)
+        n_req, n_clients = kw["n_requests"], kw["n_clients"]
+        scores = []
+        for i in range(cost.size):
+            if kw.get("geo", False):
+                _, res = run_geo_segment_raw(
+                    carry, key, pi_stack[i], lam, d, rates, avail, n_req
+                )
+            else:
+                _, res = run_segment_raw(
+                    carry, key, pi_stack[i], lam, d, rates, avail, n_req,
+                    kw.get("ttl"), kw.get("hit_latency", 0.0),
+                )
+            lat = np.asarray(res.latency)
+            fid = np.asarray(res.file_id)
+            client = fid < n_clients
+            scores.append(
+                empirical_objective(lat[client], fid[client], objective)
+                + float(cost[i])
+            )
+        calls.append((np.asarray(pi_stack), np.asarray(scores)))
+        return out
+
+    monkeypatch.setattr(router, "batched_rollout_scores", spy)
+    return calls
+
+
+def _assert_sequential_choice(rp, pi, calls):
+    """The replanner deployed the sequential reference's argmin, bitwise,
+    and its batched scores match the reference's."""
+    assert len(calls) == 1
+    pi_stack, ref = calls[0]
+    np.testing.assert_allclose(
+        np.asarray(rp.last_scores), ref, rtol=1e-5, atol=1e-5
+    )
+    best = pi_stack[int(np.argmin(ref))]
+    r = K4.size
+    np.testing.assert_array_equal(pi, best[:r])
+    if rp.repair_pi is not None:
+        np.testing.assert_array_equal(rp.repair_pi, best[r:])
 
 
 class TestReplannerParity:
-    """Batched vs sequential arbitration picks the SAME plan (bitwise)."""
+    """Batched arbitration picks the plan the sequential reference picks."""
 
-    def test_plain(self, cluster):
-        bat, seq = _pair(cluster)
+    def test_plain(self, cluster, sequential):
+        rp = _replanner(cluster.cost, _estimator(cluster))
         carry = init_carry(cluster.m)
         key = jax.random.key(9)
         avail = np.ones(cluster.m, bool)
-        pi_b = bat.replan(LAM, avail, carry=carry, key=key)
-        pi_s = seq.replan(LAM, avail, carry=carry, key=key)
-        np.testing.assert_array_equal(pi_b, pi_s)
-        np.testing.assert_allclose(
-            np.asarray(bat.last_scores), np.asarray(seq.last_scores),
-            rtol=1e-5, atol=1e-5,
-        )
-        assert len(bat.rollout_walls) == len(seq.rollout_walls) == 1
+        pi = rp.replan(LAM, avail, carry=carry, key=key)
+        _assert_sequential_choice(rp, pi, sequential)
+        assert len(rp.rollout_walls) == 1
 
-    def test_warm_start_candidates(self, cluster):
+    def test_warm_start_candidates(self, cluster, sequential):
         """pi0 doubles the candidate set (cold + warm per mask)."""
-        bat, seq = _pair(cluster)
+        rp = _replanner(cluster.cost, _estimator(cluster))
         carry = init_carry(cluster.m)
         key = jax.random.key(10)
         avail = np.ones(cluster.m, bool)
@@ -374,78 +427,56 @@ class TestReplannerParity:
             _pi_stack(cluster, 1).pi[0]
         )
         masks = [avail, np.concatenate([[False], avail[1:]])]
-        pi_b = bat.replan(
+        pi = rp.replan(
             LAM, avail, carry=carry, key=key, pi0=pi0,
             candidate_masks=masks,
         )
-        pi_s = seq.replan(
-            LAM, avail, carry=carry, key=key, pi0=pi0,
-            candidate_masks=masks,
-        )
-        np.testing.assert_array_equal(pi_b, pi_s)
-        assert np.asarray(bat.last_scores).shape == (4,)
+        _assert_sequential_choice(rp, pi, sequential)
+        assert np.asarray(rp.last_scores).shape == (4,)
 
-    def test_repair_augmented(self, cluster):
+    def test_repair_augmented(self, cluster, sequential):
         sols = _pi_stack(cluster, 1)
         placement = np.asarray(sols.pi[0]) > 1e-6
         avail = np.ones(cluster.m, bool)
         avail[0] = False
         flow = build_repair_flow(placement, K4, avail, 0.05)
-        bat, seq = _pair(cluster)
+        rp = _replanner(cluster.cost, _estimator(cluster))
         carry = init_carry(cluster.m)
         key = jax.random.key(11)
-        pi_b = bat.replan(LAM, avail, carry=carry, key=key, repair=flow)
-        pi_s = seq.replan(LAM, avail, carry=carry, key=key, repair=flow)
-        np.testing.assert_array_equal(pi_b, pi_s)
-        np.testing.assert_array_equal(bat.repair_pi, seq.repair_pi)
+        pi = rp.replan(LAM, avail, carry=carry, key=key, repair=flow)
+        assert rp.repair_pi is not None
+        _assert_sequential_choice(rp, pi, sequential)
 
-    def test_cache_aware(self, cluster):
+    def test_cache_aware(self, cluster, sequential):
         model = CacheModel(
             file_bytes=np.asarray([50.0, 50.0, 75.0, 75.0]) * MB,
             capacity_bytes=100.0 * MB,
             hit_latency=0.5,
             hot_price_per_mb=0.02,
         )
-        bat, seq = _pair(cluster, cache=model)
-        for rp in (bat, seq):
-            rp.last_ttl = model.ttl(LAM)
-            rp.last_raw = LAM.copy()
+        rp = _replanner(cluster.cost, _estimator(cluster), cache=model)
+        rp.last_ttl = model.ttl(LAM)
+        rp.last_raw = LAM.copy()
         carry = init_carry(cluster.m, cache_files=LAM.size)
         key = jax.random.key(12)
         avail = np.ones(cluster.m, bool)
         miss = model.thin(LAM)
-        pi_b = bat.replan(miss, avail, carry=carry, key=key)
-        pi_s = seq.replan(miss, avail, carry=carry, key=key)
-        np.testing.assert_array_equal(pi_b, pi_s)
-        np.testing.assert_allclose(
-            np.asarray(bat.last_scores), np.asarray(seq.last_scores),
-            rtol=1e-5, atol=1e-5,
-        )
+        pi = rp.replan(miss, avail, carry=carry, key=key)
+        _assert_sequential_choice(rp, pi, sequential)
 
-    def test_geo(self):
+    def test_geo(self, sequential):
         fabric = geo_testbed()
-        mk = lambda batched: GeoAdaptiveReplanner(
-            k=K4.copy(),
-            cost=np.asarray(fabric.cluster.cost),
-            theta=2.0,
-            estimator=EwmaMomentEstimator(prior=fabric.moments(12.5)),
-            max_iters=80,
-            rollout_requests=N_REQ,
-            rollout_batched=batched,
+        rp = _replanner(
+            fabric.cluster.cost,
+            EwmaMomentEstimator(prior=fabric.moments(12.5)),
         )
-        bat, seq = mk(True), mk(False)
         lam_cs = np.asarray(fabric.uniform_mix(4)).T * LAM
         carry = init_carry(fabric.m)
         key = jax.random.key(13)
         avail = np.ones(fabric.m, bool)
-        pi_b = bat.replan(lam_cs, avail, carry=carry, key=key)
-        pi_s = seq.replan(lam_cs, avail, carry=carry, key=key)
-        np.testing.assert_array_equal(pi_b, pi_s)
-        np.testing.assert_allclose(
-            np.asarray(bat.last_scores), np.asarray(seq.last_scores),
-            rtol=1e-5, atol=1e-5,
-        )
-        assert len(bat.rollout_walls) == 1
+        pi = rp.replan(lam_cs, avail, carry=carry, key=key)
+        _assert_sequential_choice(rp, pi, sequential)
+        assert len(rp.rollout_walls) == 1
 
     def test_scenario_outcome_reports_rollout_wall(self):
         from repro.scenarios.engine import ScenarioOutcome
